@@ -57,6 +57,7 @@ from repro import configure_logging, obs
 from repro.core import catalog
 from repro.engine import (
     BID_LIMITED_SCHEMES,
+    PallasEngine,
     ReferenceEngine,
     Scenario,
     get_engine,
@@ -340,7 +341,8 @@ def main(argv: list[str] | None = None) -> int:
 
     speedups: dict[str, float] = {}
     for name in backends:
-        engine = get_engine(name)
+        # the Pallas kernel runs interpreted, and only when asked for by name
+        engine = PallasEngine(interpret=True) if name == "pallas" else get_engine(name)
         # one untimed warm-up per candidate (allocator pools, jit compile):
         # the timed repeats then measure steady-state throughput
         engine.run(scenario)

@@ -17,11 +17,11 @@ interchangeable engine backend:
     (:mod:`repro.kernels.spot_sweep`): every scheme in **one** jit-compiled
     ``lax.scan``/``lax.while_loop`` program on ``jax.numpy`` with x64,
     billing inputs accumulated on-device; explicit opt-in via
-    ``engine="jax"``, same exact-parity contract, >= batch throughput
-    (CI-gated).
-  * :class:`PallasEngine` — the same step as a fused Pallas TPU kernel
-    (``engine="pallas"``): interpreter mode by default, native compilation
-    an explicit opt-in.
+    ``engine="jax"``, the same exact-parity contract on CPU (a TPU emulates
+    float64 and is not exact), >= batch throughput there (CI-gated).
+  * :class:`PallasEngine` — the same step as a fused Pallas TPU kernel, in
+    interpreter mode only (``PallasEngine(interpret=True)``); the float64
+    kernel does not compile natively, so a native one raises.
   * :func:`run` / :func:`run_fleet` — the one-call entry points.
 
 This is the *only* sweep surface: the long-deprecated shims

@@ -13,15 +13,17 @@ returns an :class:`EngineResult` — per-cell outcome arrays shaped
     and the CI benchmark gate).
   * :class:`~repro.engine.jax_backend.JaxEngine` — the fused multi-scheme
     spot-sweep program (one jit compile for the whole scheme set) on
-    ``jax.numpy`` with x64; explicit opt-in (``engine="jax"``), same parity
-    contract.
+    ``jax.numpy`` with x64 (``engine="jax"``); same parity contract on CPU,
+    not on a TPU (emulated float64).
   * :class:`~repro.engine.jax_backend.PallasEngine` — the same step as a
-    fused Pallas TPU kernel (``engine="pallas"``); interpreter mode by
-    default (native TPU compilation is an explicit f32-pending opt-in).
+    fused Pallas TPU kernel, in interpreter mode only
+    (``PallasEngine(interpret=True)``); the float64 kernel does not compile
+    natively, so a native one raises.
 
 ``run(scenario)`` is the one-call surface; ``engine="auto"`` picks the batch
-backend.  Every scheme is batched — ACC included — so no backend falls back
-to the scalar reference for any cell.
+backend on every platform (see :func:`get_engine` for why not the jax
+backend on a TPU).  Every scheme is batched — ACC included — so no backend
+falls back to the scalar reference for any cell.
 """
 
 from __future__ import annotations
@@ -258,14 +260,23 @@ class Engine(Protocol):
 
 def get_engine(name: str = "auto") -> Engine:
     """Resolve an engine by name: ``"reference"``, ``"batch"``, ``"jax"``,
-    ``"pallas"`` (the fused Pallas sweep kernel, interpreter mode — exact
-    but slow), or ``"auto"`` (currently the batch backend, parity-checked
-    ``==`` against the reference on every scheme, ACC included).
+    ``"pallas"`` or ``"auto"``.
 
-    Backend choice is explicit: ``"jax"`` / ``"pallas"`` raise
-    :class:`ImportError` with an install hint when jax is missing rather
-    than silently running on NumPy (the old ``REPRO_ENGINE_XP`` env hack is
-    gone).
+    ``"auto"`` is the batch backend on every platform, TPU included: it is
+    held ``==`` to the reference on every scheme, and the jax backend is not
+    on a TPU.  There XLA emulates float64 with about 49 mantissa bits and the
+    float32 exponent range, so the grid's period times and ADAPT's survival
+    tables already round on the way to the device and near-tie decisions
+    flip; ``chip_smoke.py`` prints the disagreement.  ``"auto"`` moves to a
+    device program once one is exact there (an integer substrate).
+
+    ``"pallas"`` is the native Pallas sweep kernel, which the float64 kernel
+    cannot be compiled into, so it raises :class:`NotImplementedError`; its
+    interpreter mode is asked for by constructing
+    ``PallasEngine(interpret=True)`` and passing the engine itself.
+
+    Backend choice is explicit: ``"jax"`` raises :class:`ImportError` with an
+    install hint when jax is missing rather than silently running on NumPy.
     """
     from repro.engine.batch import BatchEngine
     from repro.engine.reference import ReferenceEngine

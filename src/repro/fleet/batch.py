@@ -123,10 +123,12 @@ class _Memo:
         # (seed, bid signature, feasible set, w_bins / remaining work
         # [, decision time]) — see _BatchFleet._place_wave.  Placement is
         # scheme-independent (Eq. 8 reads history pdfs only), so these also
-        # amortize across the schemes and policies of one study.
+        # amortize across the schemes and policies of one study.  Score rows
+        # and the walks that read them are kept per scoring impl: they are
+        # that impl's arithmetic, so one engine's run never serves another's.
         self.rows: dict = {}
-        self.score_rows: dict = {}
-        self.walks: dict = {}
+        self.score_rows: dict = {}  # score_impl -> {key: row}
+        self.walks: dict = {}  # score_impl -> {key: placements}
         # ADAPT decision tables, grown as (seed, name, bid) cells appear
         self.adapt_slot: dict = {}
         self._adapt_vals: list = []
@@ -539,6 +541,8 @@ class _BatchFleet:
         # remaining * ratio[t] is bit-identical to the policy expression
         self.ratio = np.asarray([self.ref_ecu / c for c in self.cu])
         self.score_impl = score_impl
+        self.score_rows = memo.score_rows.setdefault(score_impl, {})
+        self.walks = memo.walks.setdefault(score_impl, {})
         self.horizon = {
             seed: min(t.horizon for t in traces_by_seed[seed].values())
             for seed in scenario.seeds
@@ -619,7 +623,7 @@ class _BatchFleet:
                     "div", seed, rq.cell.margin, feas_t, rq.remaining,
                     rq.cell.k if rq.k is None else rq.k,
                 )
-            pls = self.memo.walks.get(wkey)
+            pls = self.walks.get(wkey)
             if pls is None:
                 wkeys[i] = wkey
                 miss.append(i)
@@ -642,7 +646,7 @@ class _BatchFleet:
             if rq.cell.kind == "cost":
                 continue
             skey = (rq.cell.seed, sig, feats[i], rq.remaining)
-            srow = self.memo.score_rows.get(skey)
+            srow = self.score_rows.get(skey)
             if srow is None:
                 pend.append((i, skey))
             else:
@@ -667,10 +671,10 @@ class _BatchFleet:
                 WS[m] = w_scaled  # only AV-true entries reach a finite score
             eet = fleet_ops.eet_scores(P, WA, WS, AV, impl=self.score_impl)
             for m, (i, skey) in enumerate(pend):
-                scores[i] = self.memo.score_rows[skey] = eet[m]
+                scores[i] = self.score_rows[skey] = eet[m]
         for i in miss:
             pls = tuple(self._walk(reqs[i], bids_rows[i], scores.get(i)))
-            self.memo.walks[wkeys[i]] = pls
+            self.walks[wkeys[i]] = pls
             out[i] = pls
         return out
 

@@ -13,15 +13,15 @@ walks inside.  This module holds both traced realizations of that sweep:
     grid ``(cell_blocks, periods)`` with the period axis innermost
     (sequential on TPU), the per-scheme state carried in VMEM scratch across
     periods, and the per-period run records streamed to the output blocks.
-    ``interpret=True`` runs it on CPU for the parity suite.
+    It runs in interpret mode only (:data:`NATIVE_UNSUPPORTED` says why).
 
 Both build on the shared per-period orchestration
 (:func:`repro.engine.kernels.period_step_masked`) and the shared pure scheme
 kernels, so with x64 enabled the results are bit-identical to the NumPy
 driver in :mod:`repro.engine.batch` — the triad's ``ref`` — and to the scalar
 reference (asserted ``==`` by :mod:`repro.engine.parity`).  Float64 is the
-parity substrate; a real-TPU deployment would run f32 (documented in
-docs/engine.md), which is why the parity suite pins interpret mode.
+parity substrate: XLA emulates it on the TPU for the scan program, but not
+inside a Mosaic kernel.
 """
 
 from __future__ import annotations
@@ -39,6 +39,17 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core.schemes import Scheme
 from repro.engine import kernels as _k
 from repro.engine.kernels import _EPS, period_step_masked
+
+#: Why :func:`sweep_pallas` runs interpreted only: a native compile for the
+#: TPU fails as a described-v5e compile reports it (this kernel needs a port off 64-bit
+#: types; the scan program compiles, with float64 emulated by XLA).
+NATIVE_UNSUPPORTED = (
+    "the Pallas sweep kernel does not compile natively for the TPU: its "
+    "(cells, 1) blocks are off the (8, 128) tiling Mosaic requires, and XLA's "
+    "TPU x64 rewrite has no rule for float64/int64 operands of a Mosaic "
+    "kernel (UNIMPLEMENTED for tpu_custom_call); run it with interpret=True "
+    "or use engine='jax'"
+)
 
 #: Carried per-scheme state, in order (see ``period_step_masked``).
 STATE_FIELDS = ("saved", "done", "comp_time", "n_ckpt", "work_lost", "has_run", "n_kills")
@@ -466,9 +477,9 @@ def sweep_pallas(
     edges=None,
     tables=None,
     block_c: int = 256,
-    interpret: bool = False,
 ):
-    """Run the fused sweep as a Pallas kernel over cell blocks.
+    """Run the fused sweep as a Pallas kernel over cell blocks, interpreted
+    (:data:`NATIVE_UNSUPPORTED` says why it is never compiled natively).
 
     ``A/B/valid`` are the padded ``(cells, periods)`` grid arrays, ``consts``
     the scalar dict of :func:`scheme_period_step`, ``edges`` the optional
@@ -567,7 +578,7 @@ def sweep_pallas(
                 jnp.float64, jnp.bool_, jnp.int64,
             )
         ],
-        interpret=interpret,
+        interpret=True,
     )(
         A_p, B_p, valid_p, horizon_p, ptr0_p,
         edges_flat, edge_base, edge_n, tab_flat, tab_off, tab_top,

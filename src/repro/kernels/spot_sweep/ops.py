@@ -8,10 +8,10 @@ NumPy driver, whatever the implementation:
     (the triad's bit-exact reference; no jax required).
   * ``"scan"`` — the one-compile multi-scheme ``lax.scan`` program
     (:func:`repro.kernels.spot_sweep.kernel.build_sweep_scan`), jitted and
-    cached per scheme set; the default off-TPU.
-  * ``"pallas"`` — the fused Pallas kernel (TPU; the default there).
-  * ``"interpret"`` — the Pallas kernel in interpreter mode (CPU parity
-    suite; slow, test-sized grids only).
+    cached per scheme set; the default, on the TPU as elsewhere.
+  * ``"interpret"`` — the fused Pallas kernel in interpreter mode (CPU
+    parity suite; slow, test-sized grids only).  It has no native impl:
+    see :data:`repro.kernels.spot_sweep.kernel.NATIVE_UNSUPPORTED`.
 
 Device impls simulate on-device (states *and* per-period run records — the
 billing inputs — accumulate in the program) and share the vectorized NumPy
@@ -41,8 +41,6 @@ def set_impl(impl: str | None) -> None:
 
 
 def _default_impl() -> str:
-    # "pallas" (native compilation) is an explicit opt-in, never the default:
-    # the float64 parity substrate does not lower through Mosaic on TPU
     return _FORCE_IMPL if _FORCE_IMPL is not None else "scan"
 
 
@@ -81,31 +79,55 @@ def _edge_inputs(grid, t_r):
     return flat, base_m[m_of], n_m[m_of], grid.edge_ptr0(t_r)
 
 
+def scan_arrays(grid, need_edge, need_adapt, t_r, adapt_tables) -> dict:
+    """Host arrays of the scan program's array arguments, by keyword."""
+    out = {"A_T": grid.A.T, "B_T": grid.B.T, "valid_T": grid.valid.T, "horizon": grid.horizon}
+    if need_edge:
+        flat, base, n, ptr0 = _edge_inputs(grid, t_r)
+        out.update(edges_flat=flat, edge_base=base, edge_n=n, ptr0_T=ptr0.T)
+    if need_adapt:
+        out.update(
+            tab_flat=adapt_tables.flat, tab_off=adapt_tables.off, tab_top=adapt_tables.top
+        )
+    return out
+
+
+def scan_scalars(scenario, need_adapt, adapt_tables) -> dict:
+    """The scan program's scalar arguments, by keyword (traced, so a new
+    value never recompiles)."""
+    params = scenario.params
+    out = dict(
+        init_saved=float(scenario.initial_saved_work),
+        work_s=float(scenario.work_s),
+        t_c=float(params.t_c),
+        t_r=float(params.t_r),
+        hour_delta=float(params.billing_period_s),
+    )
+    if need_adapt:
+        out.update(
+            interval=float(params.adapt_interval_s),
+            bin_s=float(adapt_tables.bin_s),
+            n_bins=int(adapt_tables.n_bins),
+        )
+    return out
+
+
 def _device_arrays(grid, jnp, need_edge, need_adapt, t_r, adapt_tables):
-    """Device copies of the grid/table arrays, memoized on the grid object
+    """Device copies of :func:`scan_arrays`, memoized on the grid object
     (which :func:`repro.engine.batch.grid_and_tables` already shares per
     scenario) so repeat runs skip the host→device transfer."""
-    cache = grid.__dict__.setdefault("_sweep_device", {})
-    if "A_T" not in cache:
-        cache["A_T"] = jnp.asarray(grid.A.T)
-        cache["B_T"] = jnp.asarray(grid.B.T)
-        cache["valid_T"] = jnp.asarray(grid.valid.T)
-        cache["horizon"] = jnp.asarray(grid.horizon)
-    if need_edge and cache.get("_edge_t_r") != t_r:
-        flat, base, n, ptr0 = _edge_inputs(grid, t_r)
-        cache["edges_flat"] = jnp.asarray(flat)
-        cache["edge_base"] = jnp.asarray(base)
-        cache["edge_n"] = jnp.asarray(n)
-        cache["ptr0_T"] = jnp.asarray(ptr0.T)
-        cache["_edge_t_r"] = t_r
-    if need_adapt and cache.get("_tables_src") is not adapt_tables:
-        # keyed on the table *object*: fresh tables (different bin_s, pdfs)
-        # must never mix with a stale device copy
-        cache["tab_flat"] = jnp.asarray(adapt_tables.flat)
-        cache["tab_off"] = jnp.asarray(adapt_tables.off)
-        cache["tab_top"] = jnp.asarray(adapt_tables.top)
-        cache["_tables_src"] = adapt_tables
-    return cache
+    key = (need_edge, need_adapt, t_r)
+    cache = grid.__dict__.get("_sweep_device")
+    # the tables are matched by identity: fresh tables (different bin_s,
+    # pdfs) must never mix with a stale device copy
+    if cache is None or cache["key"] != key or cache["tables"] is not adapt_tables:
+        host = scan_arrays(grid, need_edge, need_adapt, t_r, adapt_tables)
+        cache = grid.__dict__["_sweep_device"] = {
+            "key": key,
+            "tables": adapt_tables,
+            "arrays": {k: jnp.asarray(v) for k, v in host.items()},
+        }
+    return cache["arrays"]
 
 
 def spot_sweep_grid(
@@ -192,34 +214,8 @@ def _run_device(
     run records as host arrays."""
     params = scenario.params
     if impl == "scan":
-        arrs = _device_arrays(grid, jnp, need_edge, need_adapt, params.t_r, adapt_tables)
-        kwargs = dict(
-            A_T=arrs["A_T"],
-            B_T=arrs["B_T"],
-            valid_T=arrs["valid_T"],
-            horizon=arrs["horizon"],
-            init_saved=float(scenario.initial_saved_work),
-            work_s=float(scenario.work_s),
-            t_c=float(params.t_c),
-            t_r=float(params.t_r),
-            hour_delta=delta,
-        )
-        if need_edge:
-            kwargs.update(
-                edges_flat=arrs["edges_flat"],
-                edge_base=arrs["edge_base"],
-                edge_n=arrs["edge_n"],
-                ptr0_T=arrs["ptr0_T"],
-            )
-        if need_adapt:
-            kwargs.update(
-                interval=float(params.adapt_interval_s),
-                tab_flat=arrs["tab_flat"],
-                tab_off=arrs["tab_off"],
-                tab_top=arrs["tab_top"],
-                bin_s=float(adapt_tables.bin_s),
-                n_bins=int(adapt_tables.n_bins),
-            )
+        kwargs = scan_scalars(scenario, need_adapt, adapt_tables)
+        kwargs.update(_device_arrays(grid, jnp, need_edge, need_adapt, params.t_r, adapt_tables))
         pairs = _scan_fn(schemes, jax_mod)(**kwargs)
         finals = [
             # state = (saved, done, comp_time, n_ckpt, work_lost, has_run, n_kills)
@@ -227,7 +223,7 @@ def _run_device(
             for si in range(S)
         ]
         recs_np = [tuple(np.asarray(x) for x in pairs[si][1]) for si in range(S)]  # (P, C)
-    elif impl in ("pallas", "interpret"):
+    elif impl == "interpret":
         from repro.kernels.spot_sweep import kernel as K
 
         consts = dict(
@@ -249,7 +245,6 @@ def _run_device(
         out = K.sweep_pallas(
             schemes, grid.A, grid.B, grid.valid, grid.horizon, consts,
             ptr0=ptr0, edges=edges, tables=tables, block_c=block_c,
-            interpret=impl == "interpret",
         )
         done, comp, ckpt, lost, kills, rex, rend, ruser = (np.asarray(x) for x in out)
         finals = [(done[si], comp[si], ckpt[si], lost[si], kills[si]) for si in range(S)]

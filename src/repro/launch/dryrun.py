@@ -27,7 +27,6 @@ from repro.parallel.sharding import (  # noqa: E402
     axis_rules,
     logical_sharding,
     shard_params,
-    use_compat_mesh,
 )
 from repro.train.steps import make_train_step  # noqa: E402
 
@@ -245,7 +244,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir: str, variant:
     mesh = make_production_mesh(multi_pod=multi_pod)
     chips = mesh_chip_count(mesh)
     try:
-        with use_compat_mesh(mesh):
+        with jax.sharding.set_mesh(mesh):
             t0 = time.time()
             fn, args, used_rules = build_cell(arch, shape_name, mesh, variant=variant)
             with axis_rules(used_rules):

@@ -6,14 +6,14 @@ jax device state (the dry-run must set XLA_FLAGS before first jax init).
 
 from __future__ import annotations
 
-from repro.parallel.sharding import make_compat_mesh
+import jax
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; 2 pods = 512 chips multi-pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_compat_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(shape))
 
 
 def mesh_chip_count(mesh) -> int:

@@ -14,7 +14,6 @@ from repro.kernels.flash_attention import ops as attn_ops
 from repro.models.config import ModelConfig
 from repro.models.params import ParamBuilder
 from repro.parallel import shard
-from repro.parallel.sharding import active_abstract_mesh
 
 # ---------------------------------------------------------------------------
 # Norms
@@ -145,7 +144,7 @@ def apply_attention_decode(cfg: ModelConfig, params, name: str, x, cache, *, win
         and current_rules().get("kv_seq") == "model"
         and sp_decode.sp_available(s_c)
     ):
-        mesh = active_abstract_mesh()
+        mesh = jax.sharding.get_abstract_mesh()
         sizes = dict(zip(mesh.axis_names, mesh.axis_sizes))
         data_prod = 1
         for a in ("pod", "data"):

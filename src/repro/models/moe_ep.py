@@ -26,11 +26,11 @@ from jax.sharding import PartitionSpec as P
 
 from repro.models.config import ModelConfig
 from repro.models.moe import moe_capacity
-from repro.parallel.sharding import active_abstract_mesh, compat_shard_map, current_rules
+from repro.parallel.sharding import current_rules
 
 
 def _mesh_for_ep():
-    mesh = active_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh is None or mesh.empty or "model" not in mesh.axis_names:
         return None
     return mesh
@@ -133,7 +133,7 @@ def apply_moe_ep(cfg: ModelConfig, params, name: str, x):
         in_specs.append(P("model", None, None))
         args.append(wi_gate)
     out_specs = (P(bspec, None, None), P(), P())
-    y, lb, drop = compat_shard_map(
+    y, lb, drop = jax.shard_map(
         shard_fn, mesh=mesh, in_specs=tuple(in_specs), out_specs=out_specs
     )(*args)
     return y, {"load_balance_loss": lb, "drop_frac": drop}

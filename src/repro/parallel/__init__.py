@@ -3,26 +3,18 @@ collective helpers and optional pipeline parallelism."""
 
 from repro.parallel.sharding import (
     DEFAULT_RULES,
-    active_abstract_mesh,
     axis_rules,
-    compat_shard_map,
     current_rules,
     logical_sharding,
-    make_compat_mesh,
     shard,
     shard_params,
-    use_compat_mesh,
 )
 
 __all__ = [
     "DEFAULT_RULES",
-    "active_abstract_mesh",
     "axis_rules",
-    "compat_shard_map",
     "current_rules",
     "logical_sharding",
-    "make_compat_mesh",
     "shard",
     "shard_params",
-    "use_compat_mesh",
 ]

@@ -69,53 +69,8 @@ def axis_rules(rules: Rules):
             _local.rules = prev
 
 
-def active_abstract_mesh():
-    """The mesh set by the innermost ``with mesh:`` context (or an empty one).
-
-    ``jax.sharding.get_abstract_mesh`` first shipped in jax 0.5; on older
-    installs fall back to the physical mesh that ``with mesh:`` pushes onto
-    the thread-resources env — same ``.empty``/``.axis_names``/``.axis_sizes``
-    surface, so every caller is version-agnostic.
-    """
-    get = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get is not None:
-        return get()
-    from jax.interpreters import pxla
-
-    return pxla.thread_resources.env.physical_mesh
-
-
-def make_compat_mesh(shape: tuple[int, ...], axes: tuple[str, ...]) -> Mesh:
-    """``jax.make_mesh`` with explicit-Auto axis types where the installed jax
-    supports them (``jax.sharding.AxisType`` arrived in 0.5; older versions
-    only have Auto semantics, so plain ``make_mesh`` is equivalent there)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes, axis_types=(axis_type.Auto,) * len(shape))
-    return jax.make_mesh(shape, axes)
-
-
-def compat_shard_map(f, *, mesh, in_specs, out_specs):
-    """``jax.shard_map`` (0.5+) or ``jax.experimental.shard_map.shard_map``
-    (0.4.x) — identical semantics, the symbol just moved."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm  # noqa: N813
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-
-
-def use_compat_mesh(mesh: Mesh):
-    """Context manager making ``mesh`` ambient: ``jax.sharding.set_mesh``
-    where available (jax 0.5+), else the classic ``with mesh:`` form — both
-    are what :func:`active_abstract_mesh` reads back."""
-    set_mesh = getattr(jax.sharding, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh
-
-
 def _active_mesh() -> Mesh | None:
-    mesh = active_abstract_mesh()  # set by `with mesh:` contexts
+    mesh = jax.sharding.get_abstract_mesh()  # set by `jax.sharding.set_mesh`
     if mesh is None or mesh.empty:
         return None
     return mesh

@@ -19,13 +19,12 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.parallel.sharding import active_abstract_mesh, compat_shard_map
 
 NEG_INF = -1e30
 
 
 def sp_available(s_c: int) -> bool:
-    mesh = active_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh is None or mesh.empty or "model" not in mesh.axis_names:
         return False
     tp = dict(zip(mesh.axis_names, mesh.axis_sizes))["model"]
@@ -37,7 +36,7 @@ def sp_decode_attention_update(q, k_new, v_new, k_cache, v_cache, pos, batch_div
 
     Returns (out (B,1,H,D), new_k, new_v).  ``pos``: scalar int32 append slot.
     """
-    mesh = active_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     sizes = dict(zip(mesh.axis_names, mesh.axis_sizes))
     tp = sizes["model"]
     b, _, h, d = q.shape
@@ -87,7 +86,7 @@ def sp_decode_attention_update(q, k_new, v_new, k_cache, v_cache, pos, batch_div
         out = (o_glob / jnp.maximum(l_glob, 1e-37)[..., None]).reshape(q_blk.shape[0], 1, h, d)
         return out.astype(q_blk.dtype), kc, vc
 
-    out, new_k, new_v = compat_shard_map(
+    out, new_k, new_v = jax.shard_map(
         shard_fn,
         mesh=mesh,
         in_specs=(

@@ -8,7 +8,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.parallel.sharding import make_compat_mesh
 import pytest
 
 from repro.checkpoint import CheckpointManager
@@ -124,7 +123,7 @@ def test_elastic_restore_to_shardings(tmp_path):
     dry-run exercises 512)."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    mesh = make_compat_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
     mgr = CheckpointManager(str(tmp_path))
     tree = _tree()
     mgr.save(1, tree)

@@ -92,7 +92,10 @@ def test_caches_shared_across_backends(monkeypatch):
         bids=[0.34, 0.36, 0.37],
         schemes=BID_LIMITED_SCHEMES,
     )
-    results = {name: run(sc, engine=name) for name in ("batch", "jax", "pallas")}
+    from repro.engine import PallasEngine
+
+    engines = {"batch": "batch", "jax": "jax", "pallas": PallasEngine(interpret=True)}
+    results = {name: run(sc, engine=eng) for name, eng in engines.items()}
     assert calls == {"grid": 1, "tables": 1}
     for name in ("jax", "pallas"):
         np.testing.assert_array_equal(results["batch"].cost, results[name].cost)

@@ -27,11 +27,49 @@ IT = get_instance("m1.xlarge")
 
 
 def test_registry_resolves_jax_backend():
+    from repro.engine import BatchEngine
+
     assert have_jax()
     eng = get_engine("jax")
     assert isinstance(eng, JaxEngine) and eng.name == "jax"
-    # auto stays the NumPy batch backend: jax is an explicit opt-in
-    assert get_engine("auto").name == "batch"
+    # auto is the exact NumPy batch backend (on a TPU too: see get_engine)
+    assert isinstance(get_engine("auto"), BatchEngine)
+    assert run(Scenario.from_trace(synthetic_trace(IT, 3, seed=0), 3600.0, [0.36])).engine == "batch"
+
+
+@pytest.mark.parametrize(
+    "env_dir, backend", [(None, "tpu"), (None, "cpu"), ("custom", "tpu"), ("custom", "cpu")]
+)
+def test_compile_cache_dir(tmp_path, env_dir, backend):
+    """``JAX_COMPILATION_CACHE_DIR`` wins when set; otherwise accelerator
+    programs cache at ``.jax_cache`` in the source checkout, and XLA:CPU
+    (whose entries are tied to the host's instruction set) keeps none.  The
+    backend query is steered in a fresh interpreter; nothing runs on a TPU."""
+    import os
+    import pathlib
+    import subprocess
+    import sys
+
+    env = {k: v for k, v in os.environ.items() if k != "JAX_COMPILATION_CACHE_DIR"}
+    if env_dir is not None:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / env_dir)
+    code = (
+        "import jax\n"
+        f"jax.default_backend = lambda: {backend!r}\n"
+        "from repro.engine.jax_backend import _require_jax\n"
+        "_require_jax()\n"
+        "print(jax.config.jax_compilation_cache_dir)\n"
+    )
+    out = subprocess.run(
+        [sys.executable, "-c", code], env=env, capture_output=True, text=True, timeout=120
+    )
+    assert out.returncode == 0, out.stderr
+    if env_dir is not None:
+        want = str(tmp_path / env_dir)
+    else:
+        checkout = pathlib.Path(__file__).resolve().parents[2]
+        want = str(checkout / ".jax_cache") if backend == "tpu" else "None"
+    assert out.stdout.strip().splitlines()[-1] == want
 
 
 @pytest.mark.parametrize("seed", [0, 1, 2])

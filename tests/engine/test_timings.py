@@ -57,7 +57,9 @@ def test_jax_timings_have_fused_sim_and_per_scheme_billing():
 
 def test_pallas_timings_have_fused_sim_and_per_scheme_billing():
     pytest.importorskip("jax")
-    res = get_engine("pallas").run(
+    from repro.engine import PallasEngine
+
+    res = PallasEngine(interpret=True).run(
         _scenario(schemes=(Scheme.HOUR,))  # interpreter mode: keep it tiny
     )
     _assert_phase_times(res.timings, "pallas", (Scheme.HOUR,), sim_per_scheme=False)

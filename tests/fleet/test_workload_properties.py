@@ -54,8 +54,15 @@ def test_merge_invariants(streams):
     assert len(merged) == sum(len(w) for w in streams)
     # renumbering is dense 0..n-1 and job content is conserved as a multiset
     assert sorted(j.id for j in merged) == list(range(len(merged)))
-    content = sorted((j.arrival_s, j.work_s, j.deadline_s) for j in merged)
-    assert content == sorted((j.arrival_s, j.work_s, j.deadline_s) for w in streams for j in w)
+    assert _content(merged) == _content(j for w in streams for j in w)
+
+
+def _content(jobs) -> list:
+    """Jobs as a sorted multiset of (arrival, work, deadline); a missing
+    deadline sorts before any present one instead of comparing to a float."""
+    return sorted(
+        (j.arrival_s, j.work_s, j.deadline_s is not None, j.deadline_s or 0.0) for j in jobs
+    )
 
 
 @settings(max_examples=60, deadline=None)

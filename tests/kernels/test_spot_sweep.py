@@ -123,12 +123,22 @@ def test_step_trace_edge_cases_interpret():
 
 
 def test_pallas_engine_full_parity():
-    """End to end: engine="pallas" (interpreter mode on CPU) is bit-identical
-    to the scalar reference through the public surface."""
+    """End to end: the Pallas engine in interpreter mode, asked for by name,
+    is bit-identical to the scalar reference through the public surface."""
     sc = small_scenario(bids=[0.34, 0.36, 0.37])
-    eng = get_engine("pallas")
-    assert isinstance(eng, PallasEngine) and eng.name == "pallas"
-    assert eng.impl == "interpret"  # interpreter mode is the default config
+    eng = PallasEngine(interpret=True)
+    assert eng.name == "pallas"
+    assert eng.impl == "interpret"
     report = assert_parity(sc, engine=eng)
     assert report.candidate.engine == "pallas"
     assert report.candidate.timings.impl == "interpret"
+
+
+@pytest.mark.parametrize("make", [lambda: get_engine("pallas"), lambda: PallasEngine()])
+def test_native_pallas_engine_raises_instead_of_interpreting(make):
+    """Without interpret=True the engine would need a native compile, which
+    the float64 kernel cannot get on the TPU: it says so rather than falling
+    back to the interpreter."""
+    with pytest.raises(NotImplementedError, match="does not compile natively"):
+        make()
+

@@ -14,7 +14,6 @@ import warnings; warnings.filterwarnings("ignore")
 import dataclasses
 import jax, jax.numpy as jnp, numpy as np
 
-from repro.parallel.sharding import make_compat_mesh, use_compat_mesh
 from repro.configs import get_smoke_config
 from repro.models import moe as M
 from repro.models import moe_ep as MEP
@@ -27,8 +26,8 @@ params = T.init_params(cfg, jax.random.PRNGKey(0))
 p0 = params["layers"][0]
 x = jax.random.normal(jax.random.PRNGKey(1), (4, 32, cfg.d_model), jnp.float32)
 
-mesh = make_compat_mesh((2, 4), ("data", "model"))
-with use_compat_mesh(mesh):
+mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(jax.sharding.AxisType.Auto,) * 2)
+with jax.sharding.set_mesh(mesh):
     y_ep, aux_ep = jax.jit(lambda p, x: MEP.apply_moe_ep(cfg, p, "moe", x))(p0, x)
     y_dn, aux_dn = jax.jit(lambda p, x: M.apply_moe(cfg, p, "moe", x))(p0, x)
 np.testing.assert_allclose(np.asarray(y_ep, np.float32), np.asarray(y_dn, np.float32), atol=2e-5, rtol=2e-5)
@@ -40,7 +39,7 @@ def loss(p, x):
     y, aux = MEP.apply_moe_ep(cfg, p, "moe", x)
     return jnp.sum(y.astype(jnp.float32) ** 2) + aux["load_balance_loss"]
 
-with use_compat_mesh(mesh):
+with jax.sharding.set_mesh(mesh):
     g = jax.jit(jax.grad(loss))(p0, x)
 for k, v in g.items():
     if k.startswith("moe."):

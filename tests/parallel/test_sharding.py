@@ -7,7 +7,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro.parallel.sharding import DEFAULT_RULES, _spec_for, axis_rules, current_rules, logical_sharding, make_compat_mesh
+from repro.parallel.sharding import DEFAULT_RULES, _spec_for, axis_rules, current_rules, logical_sharding
 
 
 @pytest.fixture(scope="module")
@@ -15,7 +15,7 @@ def mesh():
     if jax.device_count() < 1:
         pytest.skip("no devices")
     # single device, but axis sizes still drive divisibility logic via names
-    return make_compat_mesh((1,), ("data",))
+    return jax.make_mesh((1,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
 
 
 class FakeMesh:

@@ -11,7 +11,7 @@ import warnings; warnings.filterwarnings("ignore")
 import jax, jax.numpy as jnp, numpy as np
 from repro.configs import get_smoke_config
 from repro.models import transformer as T
-from repro.parallel.sharding import DEFAULT_RULES, axis_rules, make_compat_mesh, use_compat_mesh
+from repro.parallel.sharding import DEFAULT_RULES, axis_rules
 
 cfg = get_smoke_config("internlm2-20b")
 params = T.init_params(cfg, jax.random.PRNGKey(0))
@@ -24,9 +24,9 @@ for i in range(1, 9):
     ref_logits, cache = T.decode_step(cfg, params, tokens[:, i:i+1], cache)
 
 # SP: mesh (2 data, 4 model), kv_seq -> model, cache len 16 % 4 == 0
-mesh = make_compat_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(jax.sharding.AxisType.Auto,) * 2)
 rules = {**DEFAULT_RULES, "kv_seq": "model"}
-with use_compat_mesh(mesh), axis_rules(rules):
+with jax.sharding.set_mesh(mesh), axis_rules(rules):
     _, cache = T.prefill(cfg, params, {"tokens": tokens[:, :1]}, max_len=16, q_block=8, kv_block=8)
     step = jax.jit(lambda p, t, c: T.decode_step(cfg, p, t, c))
     sp_logits = None
