@@ -8,7 +8,7 @@ engine sweep and a contended fleet replay under it, then exports
     wall-clock spans land on the "wall clock" track, simulation-time
     events (launches, kills, checkpoints) on "simulation (1us = 1s)".
   * ``/tmp/repro_telemetry.jsonl`` — one JSON object per span / event /
-    counter / gauge, for ad-hoc analysis.
+    counter, for ad-hoc analysis.
   * a plain-text summary on stdout via :meth:`Telemetry.summary`.
 
 Run:  PYTHONPATH=src python examples/telemetry_demo.py

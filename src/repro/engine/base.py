@@ -96,7 +96,8 @@ class PhaseTimings:
         ``scheme`` attr on per-scheme backends, an ``impl`` attr on the
         fused ones), ``bill`` wraps billing per scheme, ``scalar`` wraps the
         scalar event-loop fill.  ``sim`` spans exclude their nested ``bill``
-        children via :attr:`Span.self_dur`.
+        children and keep every other child (the fused sweep's ``sim.*``
+        phases are simulation time).
         """
         grid_s = scalar_s = sim_s = 0.0
         impl = None
@@ -112,10 +113,11 @@ class PhaseTimings:
         for s in root.find("sim"):
             if "impl" in s.attrs:
                 impl = s.attrs["impl"]
+            own = s.dur - sum(c.dur for c in s.children if c.name == "bill")
             if "scheme" in s.attrs:
-                bucket(s.attrs["scheme"])["sim_s"] += s.self_dur
+                bucket(s.attrs["scheme"])["sim_s"] += own
             else:
-                sim_s += s.self_dur
+                sim_s += own
         for s in root.find("bill"):
             if "scheme" in s.attrs:
                 bucket(s.attrs["scheme"])["bill_s"] += s.dur

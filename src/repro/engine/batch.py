@@ -796,16 +796,20 @@ def _bill_runs_flat(
     costs accumulate in period (= chronological) order, so each cell's total
     is the exact left-to-right sum the scalar ``run_cost`` / ``sum(r.cost for
     r in runs)`` produces.  Also derives ``n_kills`` (non-user-terminated
-    recorded runs, exactly the scalar count).
+    recorded runs, exactly the scalar count).  Counts the runs billed in
+    ``bill.runs`` and their billing periods in ``bill.hours``.
     """
     C = grid.A.shape[0]
     total = np.zeros(C)
     n_kills = np.zeros(C, dtype=np.int64)
+    tel = obs.current()
+    tel.count("bill.runs", len(cells))
     if len(cells) == 0:
         return total, n_kills
     m_of = cells // grid.n_bids
 
     run_cost = np.zeros(len(cells))
+    hours = 0
     for m in np.unique(m_of):
         sel = np.nonzero(m_of == m)[0]
         tr = grid.markets[m].trace
@@ -813,6 +817,7 @@ def _bill_runs_flat(
         # int(math.ceil((end - launch) / Δ - 1e-12))
         n_hours = np.ceil((e_m - l_m) / delta - 1e-12).astype(np.int64)
         Q = int(n_hours.sum())
+        hours += Q
         if Q == 0:
             continue
         # one flat (run, hour) query batch: run-major, hours ascending
@@ -829,6 +834,7 @@ def _bill_runs_flat(
         # reproducing the scalar's left-to-right per-run price sum exactly
         np.add.at(rc, run_of_q[charged], price[charged])
         run_cost[sel] = rc
+    tel.count("bill.hours", hours)
 
     np.add.at(n_kills, cells[~user], 1)
     # a cell records at most one run per period, so sorting runs by (cell,

@@ -42,6 +42,7 @@ from repro.core.market import (
 from repro.core.provision import SLA
 from repro.core.schemes import Scheme, SimParams
 from repro.market import MarketParams, effective_trace
+from repro.obs import telemetry as obs
 
 #: The bid-limited schemes (an instance lives until its spot price exceeds
 #: the bid): everything except ACC, whose instances are never provider-killed.
@@ -264,25 +265,30 @@ class Scenario:
         :func:`ensemble_seed` streams (exactly the fleet-sweep recipe).  With
         ``capacity`` set, every cell's trace is the auction-cleared view (see
         :meth:`_clear_cell`) — the single point where contention enters, so
-        all backends inherit it identically.
+        all backends inherit it identically.  Timed as the ``materialize``
+        span.
         """
-        if self.traces is not None:
-            labels = self.labels or tuple(f"trace{i}" for i in range(len(self.traces)))
-            return [self._clear_cell(MarketCell(lbl, 0, tr)) for lbl, tr in zip(labels, self.traces)]
-        models, streams = [], []
-        for it in self.instances:
-            m = TraceModel.for_instance(it)
-            for s in self.seeds:
-                models.append(m)
-                streams.append(ensemble_seed(it, s))
-        traces = sample_traces_batch(models, self.horizon_days * 24 * HOUR, streams)
-        cells: list[MarketCell] = []
-        k = 0
-        for it in self.instances:
-            for s in self.seeds:
-                cells.append(self._clear_cell(MarketCell(it.name, s, traces[k], it.on_demand)))
-                k += 1
-        return cells
+        with obs.current().span("materialize"):
+            if self.traces is not None:
+                labels = self.labels or tuple(f"trace{i}" for i in range(len(self.traces)))
+                return [
+                    self._clear_cell(MarketCell(lbl, 0, tr))
+                    for lbl, tr in zip(labels, self.traces)
+                ]
+            models, streams = [], []
+            for it in self.instances:
+                m = TraceModel.for_instance(it)
+                for s in self.seeds:
+                    models.append(m)
+                    streams.append(ensemble_seed(it, s))
+            traces = sample_traces_batch(models, self.horizon_days * 24 * HOUR, streams)
+            cells: list[MarketCell] = []
+            k = 0
+            for it in self.instances:
+                for s in self.seeds:
+                    cells.append(self._clear_cell(MarketCell(it.name, s, traces[k], it.on_demand)))
+                    k += 1
+            return cells
 
     def materialize_cell(self, market: int) -> MarketCell:
         """Resolve a single market cell without generating the whole grid.

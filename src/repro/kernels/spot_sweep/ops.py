@@ -112,20 +112,29 @@ def scan_scalars(scenario, need_adapt, adapt_tables) -> dict:
     return out
 
 
-def _device_arrays(grid, jnp, need_edge, need_adapt, t_r, adapt_tables):
+def _device_arrays(grid, jnp, need_edge, need_adapt, t_r, adapt_tables, tel):
     """Device copies of :func:`scan_arrays`, memoized on the grid object
     (which :func:`repro.engine.batch.grid_and_tables` already shares per
-    scenario) so repeat runs skip the host→device transfer."""
+    scenario) so repeat runs skip the host→device transfer.  A miss is
+    timed as ``sim.inputs`` (host arrays) and ``sim.h2d`` (the copies up as
+    the host issues them, counted in ``sweep.h2d_bytes``)."""
     key = (need_edge, need_adapt, t_r)
     cache = grid.__dict__.get("_sweep_device")
     # the tables are matched by identity: fresh tables (different bin_s,
     # pdfs) must never mix with a stale device copy
     if cache is None or cache["key"] != key or cache["tables"] is not adapt_tables:
-        host = scan_arrays(grid, need_edge, need_adapt, t_r, adapt_tables)
+        with tel.span("sim.inputs"):
+            host = scan_arrays(grid, need_edge, need_adapt, t_r, adapt_tables)
+        with tel.span("sim.h2d"):
+            # not waited for: the scan waits for its inputs on the device,
+            # inside ``sim.device`` (a block_until_ready here, or on the
+            # outputs, cost a study 4-9% of its host time on a TPU v5e)
+            arrays = {k: jnp.asarray(v) for k, v in host.items()}
+        tel.count("sweep.h2d_bytes", sum(v.nbytes for v in host.values()))
         cache = grid.__dict__["_sweep_device"] = {
             "key": key,
             "tables": adapt_tables,
-            "arrays": {k: jnp.asarray(v) for k, v in host.items()},
+            "arrays": arrays,
         }
     return cache["arrays"]
 
@@ -184,7 +193,7 @@ def spot_sweep_grid(
     with tel.span("sim", impl=impl):
         finals, recs_np = _run_device(
             impl, schemes, grid, scenario, adapt_tables, jax_mod, jnp,
-            need_edge, need_adapt, delta, S, block_c,
+            need_edge, need_adapt, delta, S, block_c, tel,
         )
 
     for si, scheme in enumerate(schemes):
@@ -208,21 +217,33 @@ def spot_sweep_grid(
 
 def _run_device(
     impl, schemes, grid, scenario, adapt_tables, jax_mod, jnp,
-    need_edge, need_adapt, delta, S, block_c,
+    need_edge, need_adapt, delta, S, block_c, tel,
 ):
     """Dispatch the fused device sweep; returns per-scheme final states and
-    run records as host arrays."""
+    run records as host arrays.  The scan's phases are timed as child spans
+    of ``sim``: ``sim.inputs`` and ``sim.h2d`` (on a device-copy miss),
+    ``sim.device`` (the program, from dispatch until its first output is on
+    the host, so also the copies up still in flight) and ``sim.fetch`` (the
+    other copies down; all of them counted in ``sweep.d2h_bytes``)."""
     params = scenario.params
     if impl == "scan":
         kwargs = scan_scalars(scenario, need_adapt, adapt_tables)
-        kwargs.update(_device_arrays(grid, jnp, need_edge, need_adapt, params.t_r, adapt_tables))
-        pairs = _scan_fn(schemes, jax_mod)(**kwargs)
-        finals = [
-            # state = (saved, done, comp_time, n_ckpt, work_lost, has_run, n_kills)
-            tuple(np.asarray(pairs[si][0][j]) for j in (1, 2, 3, 4, 6))
-            for si in range(S)
-        ]
-        recs_np = [tuple(np.asarray(x) for x in pairs[si][1]) for si in range(S)]  # (P, C)
+        kwargs.update(
+            _device_arrays(grid, jnp, need_edge, need_adapt, params.t_r, adapt_tables, tel)
+        )
+        with tel.span("sim.device"):
+            pairs = _scan_fn(schemes, jax_mod)(**kwargs)
+            # copying one output down waits for the whole program, as the
+            # fetch below would; the array keeps that host copy
+            np.asarray(pairs[0][0][1])
+        with tel.span("sim.fetch"):
+            finals = [
+                # state = (saved, done, comp_time, n_ckpt, work_lost, has_run, n_kills)
+                tuple(np.asarray(pairs[si][0][j]) for j in (1, 2, 3, 4, 6))
+                for si in range(S)
+            ]
+            recs_np = [tuple(np.asarray(x) for x in pairs[si][1]) for si in range(S)]  # (P, C)
+        tel.count("sweep.d2h_bytes", sum(a.nbytes for f in finals + recs_np for a in f))
     elif impl == "interpret":
         from repro.kernels.spot_sweep import kernel as K
 

@@ -3,7 +3,7 @@
 Three views of one :class:`~repro.obs.telemetry.Telemetry` collector:
 
   * :func:`write_jsonl` — one self-describing JSON object per line
-    (``{"type": "span" | "event" | "counter" | "gauge", ...}``), the
+    (``{"type": "span" | "event" | "counter", ...}``), the
     machine-readable log for ad-hoc analysis;
   * :func:`write_chrome_trace` — the Chrome ``trace_event`` format
     (load in ``chrome://tracing`` or https://ui.perfetto.dev): spans become
@@ -11,7 +11,7 @@ Three views of one :class:`~repro.obs.telemetry.Telemetry` collector:
     events become instants on a separate *simulation* process so virtual
     hours don't stretch the wall-clock timeline;
   * :func:`summary_table` — the human-readable roll-up (per-span-name call
-    counts and wall totals, then counters and gauges).
+    counts and wall totals, then events and counters).
 """
 
 from __future__ import annotations
@@ -67,8 +67,6 @@ def write_jsonl(tel: "Telemetry", path) -> None:
         )
     for name, v in sorted(tel.counters.items()):
         lines.append(json.dumps({"type": "counter", "name": name, "value": v}))
-    for name, v in sorted(tel.gauges.items()):
-        lines.append(json.dumps({"type": "gauge", "name": name, "value": v}))
     pathlib.Path(path).write_text("\n".join(lines) + ("\n" if lines else ""))
 
 
@@ -124,7 +122,7 @@ def _jsonable(v):
 
 
 def summary_table(tel: "Telemetry") -> str:
-    """Aggregate roll-up: span wall totals by name, then counters, gauges."""
+    """Aggregate roll-up: span wall totals by name, then events, counters."""
     agg: dict[str, tuple[int, float]] = {}
     for s in tel.iter_spans():
         n, total = agg.get(s.name, (0, 0.0))
@@ -146,10 +144,5 @@ def summary_table(tel: "Telemetry") -> str:
         lines.append("")
         lines.append(f"{'counter':<28} {'value':>12}")
         for name, v in sorted(tel.counters.items()):
-            lines.append(f"{name:<28} {v:>12g}")
-    if tel.gauges:
-        lines.append("")
-        lines.append(f"{'gauge':<28} {'value':>12}")
-        for name, v in sorted(tel.gauges.items()):
             lines.append(f"{name:<28} {v:>12g}")
     return "\n".join(lines)

@@ -1,12 +1,12 @@
-"""Telemetry core: hierarchical spans, counters/gauges, and simulation events.
+"""Telemetry core: hierarchical spans, counters, and simulation events.
 
 One :class:`Telemetry` object records everything a run emits:
 
   * **spans** — wall-clock phases (grid build, per-scheme sim, billing,
     auction clearing, fleet placement/migration, ...) nested into a tree;
-  * **counters / gauges** — monotonic tallies (kills, migrations,
-    checkpoints, preemptions-by-outbid, re-clear passes, ADAPT compaction
-    steps, JIT retraces) and last-value observations;
+  * **counters** — monotonic tallies (kills, migrations, checkpoints,
+    preemptions-by-outbid, re-clear passes, ADAPT compaction steps, bytes
+    moved between host and device, billed runs and hours);
   * **events** — the paper's monitoring events (``E_ckpt`` / ``E_terminate``
     / ``E_launch`` and the framework kinds of
     :class:`repro.core.events.EventKind`) stamped with *simulation* time.
@@ -22,6 +22,11 @@ module-level :data:`NULL` no-op.  Activation is a context manager (or the
         repro.engine.run(scenario, engine="jax")
     tel.write_chrome_trace("trace.json")
 
+Once JAX is imported, an enabled collector's span also opens a
+``jax.profiler.TraceAnnotation`` of the same name for its extent, so a
+profiler trace shows every span on its host plane, on the device trace's
+clock.  This module never imports JAX itself.
+
 The zero-overhead-when-off contract: with nothing activated, every
 instrumentation site costs one global read plus either a predicate check
 (counters, events) or a shared do-nothing context manager (spans) — no
@@ -32,6 +37,7 @@ allocation, no clock read.  The engine bench gates the end-to-end cost
 from __future__ import annotations
 
 import dataclasses
+import sys
 import threading
 import time
 from typing import Any, Iterator
@@ -99,13 +105,15 @@ _NULL_SPAN_CTX = _NullSpanCtx()
 
 
 class _SpanCtx:
-    """Context manager produced by :meth:`Telemetry.span`."""
+    """Context manager produced by :meth:`Telemetry.span`; also the span's
+    profiler annotation when JAX is loaded."""
 
-    __slots__ = ("_tel", "_span")
+    __slots__ = ("_tel", "_span", "_ann")
 
     def __init__(self, tel: "Telemetry", span: Span):
         self._tel = tel
         self._span = span
+        self._ann = None
 
     def __enter__(self) -> Span:
         tel = self._tel
@@ -113,17 +121,23 @@ class _SpanCtx:
         stack = tel._stack
         (stack[-1].children if stack else tel.spans).append(span)
         stack.append(span)
+        profiler = sys.modules.get("jax.profiler")
+        if profiler is not None:
+            self._ann = profiler.TraceAnnotation(span.name)
+            self._ann.__enter__()
         span.t0 = time.perf_counter() - tel.epoch
         return span
 
     def __exit__(self, *exc):
         span = self._tel._stack.pop()
         span.dur = time.perf_counter() - self._tel.epoch - span.t0
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         return False
 
 
 class Telemetry:
-    """A live collector of spans, counters, gauges, and simulation events.
+    """A live collector of spans, counters, and simulation events.
 
     Entering the object activates it (instrumented library code then reports
     here via :func:`current`); exiting deactivates it.  A collector can also
@@ -136,7 +150,6 @@ class Telemetry:
         self.epoch = time.perf_counter()
         self.spans: list[Span] = []  # root spans, in emission order
         self.counters: dict[str, float] = {}
-        self.gauges: dict[str, float] = {}
         self.events: list[SimEvent] = []
         # span nesting is tracked per thread: one collector may receive spans
         # from several worker threads (e.g. `repro-suite run --jobs N`) and a
@@ -163,10 +176,6 @@ class Telemetry:
         """Add ``value`` to the monotonic counter ``name``."""
         with self._lock:
             self.counters[name] = self.counters.get(name, 0) + value
-
-    def gauge(self, name: str, value: float) -> None:
-        """Record the latest observation of ``name``."""
-        self.gauges[name] = value
 
     def event(self, name: str, t: float, **attrs) -> None:
         """Record a simulation-time event (``t`` in simulation seconds)."""
@@ -234,9 +243,6 @@ class _NullTelemetry(Telemetry):
         return _NULL_SPAN_CTX
 
     def count(self, name: str, value: float = 1) -> None:
-        pass
-
-    def gauge(self, name: str, value: float) -> None:
         pass
 
     def event(self, name: str, t: float, **attrs) -> None:

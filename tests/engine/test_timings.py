@@ -55,6 +55,19 @@ def test_jax_timings_have_fused_sim_and_per_scheme_billing():
     assert res.timings.impl == "scan"
 
 
+def test_jax_sim_s_keeps_the_sweep_phases():
+    """``sim_s`` leaves out only billing: the sweep's ``sim.*`` phases are
+    simulation time."""
+    pytest.importorskip("jax")
+    from repro import obs
+
+    with obs.Telemetry() as tel:
+        res = get_engine("jax").run(_scenario())
+    phases = [c for s in tel.find_spans("sim") for c in s.children if c.name.startswith("sim.")]
+    assert {c.name for c in phases} == {"sim.inputs", "sim.h2d", "sim.device", "sim.fetch"}
+    assert res.timings.sim_s >= sum(c.dur for c in phases) > 0
+
+
 def test_pallas_timings_have_fused_sim_and_per_scheme_billing():
     pytest.importorskip("jax")
     from repro.engine import PallasEngine

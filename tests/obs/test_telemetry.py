@@ -15,7 +15,6 @@ def test_current_is_null_when_nothing_active():
     with tel.span("anything") as s:
         assert s is None
     tel.count("x")
-    tel.gauge("y", 1.0)
     tel.event("z", 0.0)
 
 
@@ -65,12 +64,10 @@ def test_counters_gauges_events():
     tel = obs.Telemetry()
     tel.count("kills")
     tel.count("kills", 2)
-    tel.gauge("price", 0.3)
-    tel.gauge("price", 0.7)
     tel.event("E_ckpt", 3600.0, price=0.5)
     assert tel.counter("kills") == 3
     assert tel.counter("never") == 0
-    assert tel.gauges["price"] == 0.7
+    assert not hasattr(tel, "gauge") and not hasattr(tel, "gauges")
     (ev,) = tel.events
     assert (ev.name, ev.t, ev.attrs) == ("E_ckpt", 3600.0, {"price": 0.5})
     assert ev.wall >= 0.0
@@ -82,7 +79,6 @@ def _populated():
         with tel.span("sim", scheme="hour"):
             pass
     tel.count("fleet.kills", 4)
-    tel.gauge("ewma_ms", 12.5)
     tel.event("E_terminate", 7200.0, at=7200.0)
     return tel
 
@@ -100,8 +96,8 @@ def test_write_jsonl(tmp_path):
     assert by_type["span"][1]["attrs"] == {"scheme": "hour"}
     assert by_type["event"][0]["name"] == "E_terminate"
     assert by_type["event"][0]["sim_t_s"] == 7200.0
-    assert by_type["counter"][0] == {"type": "counter", "name": "fleet.kills", "value": 4}
-    assert by_type["gauge"][0] == {"type": "gauge", "name": "ewma_ms", "value": 12.5}
+    assert by_type["counter"] == [{"type": "counter", "name": "fleet.kills", "value": 4}]
+    assert set(by_type) == {"span", "event", "counter"}
 
 
 def test_write_chrome_trace(tmp_path):
@@ -124,7 +120,7 @@ def test_summary_table_sections():
     out = _populated().summary()
     assert "engine.run" in out
     assert "fleet.kills" in out
-    assert "ewma_ms" in out
+    assert "gauge" not in out
     assert "E_terminate" in out
 
 
