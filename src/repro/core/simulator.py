@@ -364,7 +364,11 @@ def simulate_acc_attempt(
 
 def _next_launch_time(trace: PriceTrace, t_from: float, a_bid: float, poll_s: float) -> float | None:
     """First poll tick >= t_from with price <= A_bid (paper: user-defined poll)."""
-    t = math.ceil(t_from / poll_s - _EPS) * poll_s
+    return _poll_walk(trace, math.ceil(t_from / poll_s - _EPS) * poll_s, a_bid, poll_s)
+
+
+def _poll_walk(trace: PriceTrace, t: float, a_bid: float, poll_s: float) -> float | None:
+    """The poll walk of :func:`_next_launch_time` from its opening tick ``t``."""
     while t < trace.horizon:
         if trace.price_at(t) <= a_bid:
             return t

@@ -21,8 +21,9 @@ binned survival tables per (market, bid) cell (:class:`AdaptTables`), so it
 advances in lockstep like the other schemes instead of falling back to the
 scalar loop.  ACC — a different control loop entirely (bid-unlimited leases,
 poll-driven relaunch) — runs as a cell-decoupled seek/lease state machine
-(:func:`_run_acc`) over the same period grid, so no scheme falls back to the
-per-cell scalar path anymore.
+(:func:`_run_acc`) over the same period grid, each seek resolved in one step
+from a per-period launch-tick table wherever the poll lattice is exact, so no
+scheme falls back to the per-cell scalar path anymore.
 
 Exactness is the design contract, not an aspiration (see
 :mod:`repro.engine.kernels` and :mod:`repro.engine.parity`): parity with the
@@ -31,12 +32,15 @@ scalar reference is asserted ``==``, not ``allclose``.
 
 from __future__ import annotations
 
+import math
 import time
 import weakref
+from fractions import Fraction
 
 import numpy as np
 
 from repro.core.schemes import Scheme
+from repro.core.simulator import _poll_walk
 from repro.engine.base import EngineResult, PhaseTimings, empty_result, fold_result_counters
 from repro.engine.kernels import (
     _EPS,
@@ -182,6 +186,8 @@ class _PeriodGrid:
         # lazy EDGE support: (per-market edge arrays, flat, base, counts)
         self._edges: tuple | None = None
         self._edge_ptr0: np.ndarray | None = None
+        # lazy ACC launch tables, keyed by poll period
+        self._acc_launch: dict[float, tuple[np.ndarray, np.ndarray]] = {}
 
     @staticmethod
     def build(markets: list[MarketCell], scenario: Scenario) -> "_PeriodGrid":
@@ -235,6 +241,25 @@ class _PeriodGrid:
                 )
             self._edge_ptr0 = ptr
         return self._edge_ptr0
+
+    def acc_launch(self, poll: float) -> tuple[np.ndarray, np.ndarray]:
+        """ACC's launch table on the poll lattice ``k * poll``: ``(TK, NXT)``.
+
+        ``TK[c, p]`` is the first lattice tick at or after ``A[c, p]`` — the
+        tick ``_next_launch_time`` reaches first inside period ``p`` (the
+        ``ceil(x / poll - eps)`` tick falls one step short when ``A`` lies
+        within ``eps * poll`` above a tick).  ``NXT[c, q]`` (``P + 1``
+        columns) is the first period ``p >= q`` whose tick lands inside it
+        (``TK < B``), or ``P`` when none does; NaN pads never qualify."""
+        if poll not in self._acc_launch:
+            C, P = self.A.shape
+            TK = np.ceil(self.A / poll - _EPS) * poll
+            TK = np.where(TK < self.A, TK + poll, TK)
+            lands = np.where(self.valid & (TK < self.B), np.arange(P), P)
+            NXT = np.full((C, P + 1), P, dtype=np.int64)
+            NXT[:, :P] = np.minimum.accumulate(lands[:, ::-1], axis=1)[:, ::-1]
+            self._acc_launch[poll] = (TK, NXT)
+        return self._acc_launch[poll]
 
     def edge_state(self, cells: np.ndarray, period: int, t_r: float):
         """Per-cell edge cursors for :func:`_kernel_windows` (EDGE mode):
@@ -554,13 +579,14 @@ def _run_acc(grid: _PeriodGrid, scenario: Scenario) -> dict[str, np.ndarray]:
     first admissible poll tick, is never provider-killed, and walks hour
     boundaries to completion, self-termination, or the horizon
     (``simulator._simulate_acc``).  Each lane is one (market, bid) cell in
-    one of two modes — *seeking* (the ``_next_launch_time`` poll walk,
-    replicated step for step because the visited poll ticks are
-    path-dependent float lattice values) or *in-lease* (hour ticks via
-    :func:`repro.engine.kernels.acc_lease_tick`, the leased-work variant of
-    ``windows_advance``).
+    one of two modes — *seeking* (``_next_launch_time``'s poll walk) or
+    *in-lease* (hour ticks via :func:`repro.engine.kernels.acc_lease_tick`,
+    the leased-work variant of ``windows_advance``).  Every pass of the loop
+    resolves each seeking lane's whole walk and takes one hour tick for each
+    lane in a lease, so the loop makes about as many passes as the busiest
+    cell has hour ticks, however many price changes a seek waits through.
 
-    Two vectorization devices make this exact *and* cheap:
+    Two devices make this exact *and* cheap:
 
     * ``price_at(t) <= a_bid`` iff ``t`` falls inside an availability period
       of the cell — the same float comparisons ``available_periods`` made on
@@ -568,22 +594,35 @@ def _run_acc(grid: _PeriodGrid, scenario: Scenario) -> dict[str, np.ndarray]:
       monotone in ``t`` (seek ticks, then ``t_cd < t_td`` per hour, then the
       relaunch seek), so one forward-only per-lane period cursor answers all
       membership queries in amortized O(1).
-    * A seeking lane whose cursor has run out of periods (no availability
-      ends after the current tick) can never launch again; it is retired
-      immediately instead of polling segment by segment to the horizon — the
-      scalar walk returns ``None`` there with no observable state change.
+    * When every poll tick ``k * poll`` up to the horizon is a float64 value
+      exactly (:func:`_poll_lattice_exact`; any whole-second poll), the walk
+      only ever stands on those ticks, each step ``t + poll`` lands on the
+      next one, and it can skip none: its next stop never passes the first
+      tick at or after the next period's start.  So from an opening tick
+      outside every period it stops first inside period ``p`` at
+      ``TK[c, p]`` and launches at the first period whose tick lands inside
+      it — a lookup in :meth:`_PeriodGrid.acc_launch`, with no walk over the
+      price changes.  A lane with no such period before the horizon retires,
+      as the scalar walk returns ``None`` with no observable state change.
+
+    On a lattice that rounds, ``t + poll`` and ``k * poll`` can part by an
+    ulp and the ticks depend on the path, so there each seeking lane runs
+    the scalar walk (:func:`repro.core.simulator._poll_walk`) itself.
 
     Self-terminated lanes re-enter seek from ``terminated_at + _EPS``; a
     lease that runs off the horizon is billed OUT_OF_BID-style over
     ``[launch, horizon)`` with no work_lost charge, mirroring the scalar.
     ACC reports ``n_kills = 0`` (never provider-killed), so the
-    kill-counting half of :func:`_bill_runs_flat` is discarded.
+    kill-counting half of :func:`_bill_runs_flat` is discarded.  Counters
+    ``acc.seeks`` (seek episodes resolved: launches plus retirements) and
+    ``acc.passes`` (passes of the loop) show the cost stays with the hours.
     """
     params = scenario.params
     work_s = scenario.work_s
     t_r, t_c, t_w = params.t_r, params.t_c, params.t_w
     delta, poll = params.billing_period_s, params.poll_s
     C, P = grid.A.shape
+    tel = obs.current()
 
     done = np.zeros(C, dtype=bool)
     comp_time = np.full(C, np.inf)
@@ -605,15 +644,14 @@ def _run_acc(grid: _PeriodGrid, scenario: Scenario) -> dict[str, np.ndarray]:
         Re.append(ev)
         Ru.append(np.full(len(cv), user, dtype=bool))
 
-    # padded per-market boundary times: vectorized trace.next_change
-    tlists = [m.trace.times for m in grid.markets]
-    Tpad = np.full((grid.n_markets, max(len(tt) for tt in tlists) + 1), np.inf)
-    for m_i, tt in enumerate(tlists):
-        Tpad[m_i, : len(tt)] = tt
+    exact = _poll_lattice_exact(poll, float(grid.horizon.max(initial=0.0)))
+    if exact:
+        TK, NXT = grid.acc_launch(poll)
+    else:
+        bid_c = np.concatenate([scenario.market_bids(m) for m in grid.markets])
 
     idx = np.arange(C)  # global cell ids of the active set
     N = C
-    m_a = idx // grid.n_bids
     pcnt_a = grid.valid.sum(axis=1)
     hor_a = grid.horizon
     ptr = np.zeros(N, dtype=np.int64)  # per-lane monotone period cursor
@@ -632,43 +670,49 @@ def _run_acc(grid: _PeriodGrid, scenario: Scenario) -> dict[str, np.ndarray]:
         return mask & (ptr < pcnt_a) & (grid.A[idx, pc] <= tq) & (tq < grid.B[idx, pc])
 
     alive = np.ones(N, dtype=bool)
+    seeking = np.ones(N, dtype=bool)
     sv = np.full(N, float(scenario.initial_saved_work))
     L = np.zeros(N)
     t = np.zeros(N)
     work = np.zeros(N)
     kk = np.ones(N, dtype=np.int64)  # hour index within the current lease
     ordn = np.zeros(N, dtype=np.int64)
-    # immediate launch at t=0 when the opening price already admits the bid;
-    # everyone else starts the poll walk from ceil(0/poll - eps) * poll
-    adm0 = admissible(alive, np.zeros(N))
-    seeking = ~adm0
-    ts = np.where(seeking, np.ceil(0.0 / poll - _EPS) * poll, 0.0)
-    work = np.where(adm0, sv, work)
-    t = np.where(adm0, t_r, t)  # L = 0.0, t = L + t_r
+    # each seeking lane's opening poll tick: 0.0 at the start (the scalar
+    # launches there when the opening price already admits the bid)
+    ts = np.zeros(N)
+    n_seeks = n_passes = 0
 
     while alive.any():
-        # -- seek: walk every seeking lane to its launch tick (or retire it)
+        n_passes += 1
+        # -- seek: resolve every seeking lane's launch tick (or retire it)
         seek = alive & seeking
-        while seek.any():
-            dead = seek & (ts >= hor_a)
-            ok = admissible(seek & ~dead, ts)
-            # cursor exhausted: no availability ends after ts — never launches
-            dead |= seek & ~dead & ~ok & (ptr >= pcnt_a)
-            alive &= ~dead
-            seek &= ~dead
-            if ok.any():
-                L = np.where(ok, ts, L)
-                t = np.where(ok, ts + t_r, t)  # t = L + t_r
-                work = np.where(ok, sv, work)
-                kk = np.where(ok, 1, kk)
-                seeking &= ~ok
-                seek &= ~ok
-            rows = np.nonzero(seek)[0]
+        if seek.any():
+            n_seeks += int(seek.sum())
+            # launch at the opening tick when it is inside a period (never
+            # past the horizon: every period ends by then)
+            ok = admissible(seek, ts)
+            rows = np.nonzero(seek & ~ok)[0]
             if rows.size:
-                # t = max(t + poll, ceil(next_change(t)/poll - eps) * poll)
-                j = (Tpad[m_a[rows]] <= ts[rows, None]).sum(axis=1)
-                nxt = Tpad[m_a[rows], j]
-                ts[rows] = np.maximum(ts[rows] + poll, np.ceil(nxt / poll - _EPS) * poll)
+                if exact:
+                    # first period from the cursor whose tick lands inside
+                    # it, so TK < B <= horizon; none left: P, retire
+                    p_star = NXT[idx[rows], ptr[rows]]
+                    tk = TK[idx[rows], np.minimum(p_star, P - 1)]
+                    go = p_star < P
+                    ptr[rows[go]] = p_star[go]
+                else:
+                    tk = np.array(
+                        [_walk_or_nan(grid, bid_c, idx[r], ts[r], poll) for r in rows]
+                    )
+                    go = ~np.isnan(tk)
+                ts[rows[go]] = tk[go]
+                ok[rows[go]] = True
+                alive[rows[~go]] = False
+            L = np.where(ok, ts, L)
+            t = np.where(ok, ts + t_r, t)  # t = L + t_r
+            work = np.where(ok, sv, work)
+            kk = np.where(ok, 1, kk)
+            seeking &= ~ok
 
         live = alive & ~seeking
         if not live.any():
@@ -717,15 +761,17 @@ def _run_acc(grid: _PeriodGrid, scenario: Scenario) -> dict[str, np.ndarray]:
         # -- compact: drop finished cells so the tail runs on small arrays
         na = int(alive.sum())
         if na and na <= N // 2:
-            obs.current().count("acc.compactions")
+            tel.count("acc.compactions")
             keep = alive
-            idx, pcnt_a, hor_a, m_a = idx[keep], pcnt_a[keep], hor_a[keep], m_a[keep]
+            idx, pcnt_a, hor_a = idx[keep], pcnt_a[keep], hor_a[keep]
             ptr, sv, L, t, work = ptr[keep], sv[keep], L[keep], t[keep], work[keep]
             kk, ts, ordn, seeking = kk[keep], ts[keep], ordn[keep], seeking[keep]
             alive = np.ones(na, dtype=bool)
             N = na
 
-    with obs.current().span("bill", scheme=Scheme.ACC.value):
+    tel.count("acc.seeks", n_seeks)
+    tel.count("acc.passes", n_passes)
+    with tel.span("bill", scheme=Scheme.ACC.value):
         if Rc:
             total, _ = _bill_runs_flat(
                 grid,
@@ -748,6 +794,21 @@ def _run_acc(grid: _PeriodGrid, scenario: Scenario) -> dict[str, np.ndarray]:
         "work_lost_s": work_lost,
         "n_self_terminations": n_term,
     }
+
+
+def _poll_lattice_exact(poll: float, horizon: float) -> bool:
+    """Whether every poll tick ``k * poll`` up to two past ``horizon`` is a
+    float64 value exactly (``poll = n / 2**s`` with ``k * n < 2**53``), so
+    that ``k * poll + poll == (k + 1) * poll`` and the seek's ticks do not
+    depend on its path."""
+    return Fraction(poll).numerator * (math.ceil(horizon / poll) + 2) < 2**53
+
+
+def _walk_or_nan(grid: _PeriodGrid, bid_c: np.ndarray, cell: int, tick: float, poll: float):
+    """The scalar poll walk of one cell from ``tick`` (NaN for ``None``)."""
+    trace = grid.markets[cell // grid.n_bids].trace
+    launch = _poll_walk(trace, float(tick), float(bid_c[cell]), poll)
+    return np.nan if launch is None else launch
 
 
 # ---------------------------------------------------------------------------
