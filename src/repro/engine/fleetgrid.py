@@ -104,6 +104,7 @@ def fleet_inputs(scenario: FleetScenario) -> _FleetInputs:
     Keyed only on the fields that determine traces and workloads, so scheme /
     margin / policy variations of one study share a single trace grid and
     memo — and benchmark repeats of the same scenario are pure cache hits.
+    A miss builds them under a ``fleet.inputs`` span.
     """
     key = (
         scenario.sla, scenario.n_types, tuple(scenario.seeds), scenario.horizon_days,
@@ -114,24 +115,25 @@ def fleet_inputs(scenario: FleetScenario) -> _FleetInputs:
     if inp is None:
         from repro.fleet.batch import _Memo
 
-        types = select_types(scenario.sla, scenario.n_types)
-        traces_by_seed = batched_fleet_traces(types, scenario.seeds, scenario.horizon_days)
-        hist_by_seed = batched_fleet_traces(
-            types, scenario.seeds, scenario.horizon_days, history=True
-        )
-        workloads = {
-            seed: Workload.poisson(
-                scenario.n_jobs,
-                scenario.mean_interarrival_s,
-                scenario.mean_work_h * HOUR,
-                seed=seed,
-                sla=scenario.sla,
-                deadline_slack=scenario.deadline_slack,
+        with obs.current().span("fleet.inputs"):
+            types = select_types(scenario.sla, scenario.n_types)
+            traces_by_seed = batched_fleet_traces(types, scenario.seeds, scenario.horizon_days)
+            hist_by_seed = batched_fleet_traces(
+                types, scenario.seeds, scenario.horizon_days, history=True
             )
-            for seed in scenario.seeds
-        }
-        inp = _FleetInputs(types, traces_by_seed, hist_by_seed, workloads,
-                           _Memo(traces_by_seed, hist_by_seed))
+            workloads = {
+                seed: Workload.poisson(
+                    scenario.n_jobs,
+                    scenario.mean_interarrival_s,
+                    scenario.mean_work_h * HOUR,
+                    seed=seed,
+                    sla=scenario.sla,
+                    deadline_slack=scenario.deadline_slack,
+                )
+                for seed in scenario.seeds
+            }
+            inp = _FleetInputs(types, traces_by_seed, hist_by_seed, workloads,
+                               _Memo(traces_by_seed, hist_by_seed))
         while len(_INPUTS_CACHE) >= _INPUTS_CACHE_MAX:
             _INPUTS_CACHE.pop(next(iter(_INPUTS_CACHE)))
         _INPUTS_CACHE[key] = inp

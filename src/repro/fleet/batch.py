@@ -33,7 +33,11 @@ scenarios to the scalar controller.  Telemetry differences are documented in
 ``docs/fleet.md``: the batch engine emits the same ``fleet.*`` *counters*
 (bit-identical totals) and per-cell ``fleet.cell`` spans, but skips the
 controller's per-event ``tel.event`` stream and per-job ``fleet.place`` /
-``fleet.migrate`` spans.
+``fleet.migrate`` spans.  Its own phases have spans of their own:
+``fleet.place_wave`` and ``fleet.sim_wave`` per wave, and ``fleet.replay``
+around phase 2, with the counters ``fleet_batch.rounds`` and
+``fleet_batch.placements`` (outside ``fleet.*``, whose counters are the
+controller's).
 """
 
 from __future__ import annotations
@@ -41,6 +45,7 @@ from __future__ import annotations
 import dataclasses
 import heapq
 import math
+import sys
 
 import numpy as np
 
@@ -378,20 +383,19 @@ def acc_attempts_batched(
 def _bill_flat(trace: PriceTrace, launch, end, user, delta: float) -> np.ndarray:
     """``billing.run_cost`` for many runs on one trace at once.
 
-    Flat-expands every run's billing periods (``start = launch + k*Δ``) and
-    scatter-adds charged period prices per run.  The flat order is per-run
-    ``k``-ascending, so each run's float accumulation order — and therefore
-    its cost bit pattern — matches the scalar ``sum`` in ``run_cost``.
+    Flat-expands every run's billing periods (``start = launch + k*Δ``) into
+    a ``(run, k)`` matrix of charged period prices (zero where uncharged)
+    and sums each row with :func:`_python_sum_rows`, the arithmetic of the
+    scalar ``sum`` in ``run_cost``, so every cost has its bit pattern.
     """
     launch = np.asarray(launch, dtype=np.float64)
     end = np.asarray(end, dtype=np.float64)
     user = np.asarray(user, dtype=bool)
     n = np.ceil((end - launch) / delta - 1e-12).astype(np.int64)
     n = np.maximum(n, 0)
-    costs = np.zeros(len(launch))
     total = int(n.sum())
     if total == 0:
-        return costs
+        return np.zeros(len(launch))
     att = np.repeat(np.arange(len(launch)), n)
     off = np.cumsum(n) - n
     kk = np.arange(total, dtype=np.int64) - np.repeat(off, n)
@@ -399,8 +403,29 @@ def _bill_flat(trace: PriceTrace, launch, end, user, delta: float) -> np.ndarray
     full = start + delta <= end[att] + 1e-9
     charged = full | user[att]
     seg = np.clip(np.searchsorted(trace.times, start, side="right") - 1, 0, len(trace.prices) - 1)
-    np.add.at(costs, att[charged], trace.prices[seg][charged])
-    return costs
+    prices = np.zeros((len(launch), int(n.max())))
+    prices[att[charged], kk[charged]] = trace.prices[seg][charged]
+    return _python_sum_rows(prices)
+
+
+def _python_sum_rows(x: np.ndarray) -> np.ndarray:
+    """Each row's ``sum()`` of Python floats, left to right, bit for bit.
+
+    Python 3.12's ``sum`` compensates float addition (Neumaier): it keeps the
+    rounding error of each partial sum ``t = s + x`` in ``c`` and adds ``c``
+    once at the end, when it is non-zero and finite.  The partial sums are a
+    sequential ``cumsum`` along the row; the errors follow elementwise.
+    Zeros (uncharged periods, padding) leave both the sum and ``c`` as they
+    are.  Before 3.12 ``sum`` adds plainly, which is the ``cumsum`` alone.
+    """
+    t = np.cumsum(x, axis=1)
+    if sys.version_info < (3, 12):
+        return t[:, -1].copy()
+    s = np.concatenate([np.zeros((len(x), 1)), t[:, :-1]], axis=1)
+    err = np.where(np.abs(s) >= np.abs(x), (s - t) + x, (x - t) + s)
+    c = np.cumsum(err, axis=1)[:, -1]
+    out = t[:, -1]
+    return np.where((c != 0.0) & np.isfinite(c), out + c, out)
 
 
 # ---------------------------------------------------------------------------
@@ -580,9 +605,19 @@ class _BatchFleet:
     # -- placement waves -----------------------------------------------------
 
     def _place_wave(self, reqs):
+        """One placement wave under a ``fleet.place_wave`` span, its lanes
+        counted in ``fleet_batch.placements``.  Returns ``[(ti, bid), ...]`` per
+        request."""
+        if not reqs:
+            return []
+        tel = obs.current()
+        tel.count("fleet_batch.placements", len(reqs))
+        with tel.span("fleet.place_wave"):
+            return self._place(reqs)
+
+    def _place(self, reqs):
         """Score one EET matrix for the wave, then run each request's exact
-        policy tie-break walk on its row.  Returns ``[(ti, bid), ...]`` per
-        request.
+        policy tie-break walk on its row.
 
         Everything derived along the way is memoized on the quantities that
         fully determine it.  A finished walk depends only on
@@ -595,8 +630,6 @@ class _BatchFleet:
         time/replicas, and assembled ``(p_fail, wasted, avail)`` rows are
         keyed on the per-type ``w_bins`` quantization — remaining work
         enters Eq. 8 only through the bin count and the ``w_scaled`` term."""
-        if not reqs:
-            return []
         n = len(reqs)
         out = [None] * n
         sigs = [None] * n  # bid signature: ("a1", uniform bid) | ("m", margin)
@@ -754,15 +787,21 @@ class _BatchFleet:
     # -- sim waves -----------------------------------------------------------
 
     def _sim_wave(self, spawns):
-        """Simulate every spawned attempt: launch/kill boundaries per
-        ``(seed, type, bid)`` group, one shared-kernel call over all go lanes,
-        flat-expanded billing per group.  Fills ``sp.att`` (None where the
-        scalar returns None)."""
+        """Simulate every spawned attempt under a ``fleet.sim_wave`` span
+        (attribute ``scheme``).  Fills ``sp.att`` (None where the scalar
+        returns None)."""
         if not spawns:
             return
-        if self.scheme == Scheme.ACC:
-            self._sim_wave_acc(spawns)
-            return
+        with obs.current().span("fleet.sim_wave", scheme=self.scheme.value):
+            if self.scheme == Scheme.ACC:
+                self._sim_wave_acc(spawns)
+            else:
+                self._sim_wave_bid_limited(spawns)
+
+    def _sim_wave_bid_limited(self, spawns):
+        """Bid-limited wave: launch/kill boundaries per ``(seed, type, bid)``
+        group, one shared-kernel call over all go lanes, flat-expanded
+        billing per group."""
         t_r = self.params.t_r
         delta = self.params.billing_period_s
         groups: dict = {}
@@ -948,9 +987,11 @@ class _BatchFleet:
     # -- phase 1: rounds -----------------------------------------------------
 
     def run(self):
+        tel = obs.current()
         self._arrivals()
+        tel.count("fleet_batch.rounds")
         while self._round():
-            pass
+            tel.count("fleet_batch.rounds")
         return self._replay_all()
 
     def _attach(self, spawns):
@@ -1101,13 +1142,16 @@ class _BatchFleet:
     # -- phase 2: per-cell replay -------------------------------------------
 
     def _replay_all(self):
+        """Phase 2 under a ``fleet.replay`` span, one ``fleet.cell`` child
+        per cell."""
         results = {}
         tel = obs.current()
-        for cell in self.cells:
-            with tel.span(
-                "fleet.cell", policy=cell.policy.name, margin=cell.margin, seed=cell.seed
-            ):
-                results[cell.key] = self._replay_cell(cell, tel)
+        with tel.span("fleet.replay"):
+            for cell in self.cells:
+                with tel.span(
+                    "fleet.cell", policy=cell.policy.name, margin=cell.margin, seed=cell.seed
+                ):
+                    results[cell.key] = self._replay_cell(cell, tel)
         return results
 
     def _record(self, att, end, termination, cost, killed, completed, cancelled,

@@ -19,6 +19,7 @@ from __future__ import annotations
 import numpy as np
 
 from repro.obs import retrace
+from repro.obs import telemetry as obs
 
 _FORCE_IMPL: str | None = None
 
@@ -72,16 +73,33 @@ def eet_scores(
     avail: np.ndarray,
     impl: str | None = None,
 ) -> np.ndarray:
-    """Eq. 8 scores for one ``(lane, type)`` wave; see :mod:`.ref`."""
+    """Eq. 8 scores for one ``(lane, type)`` wave; see :mod:`.ref`.
+
+    Each call is a ``fleet.score`` span (attribute ``impl``), from the
+    padding to the scores on the host, and counts ``fleet_step.calls``,
+    ``fleet_step.lanes`` (the wave's lanes) and ``fleet_step.cells``
+    (lanes × types as scored, padded lanes included); the ``"jax"`` impl
+    also counts the bytes it copies to the device and back in
+    ``fleet_step.h2d_bytes`` / ``fleet_step.d2h_bytes``."""
     if impl is None:
         impl = _default_impl()
-    if impl == "numpy":
-        from repro.kernels.fleet_step.ref import eet_scores_numpy
-
-        return eet_scores_numpy(p_fail, wasted, w_scaled, avail)
-    if impl != "jax":
+    if impl not in ("numpy", "jax"):
         raise ValueError(f"unknown fleet_step impl {impl!r}")
+    tel = obs.current()
+    with tel.span("fleet.score", impl=impl):
+        tel.count("fleet_step.calls")
+        tel.count("fleet_step.lanes", p_fail.shape[0])
+        if impl == "numpy":
+            from repro.kernels.fleet_step.ref import eet_scores_numpy
 
+            tel.count("fleet_step.cells", p_fail.size)
+            return eet_scores_numpy(p_fail, wasted, w_scaled, avail)
+        return _eet_scores_jax(p_fail, wasted, w_scaled, avail, tel)
+
+
+def _eet_scores_jax(p_fail, wasted, w_scaled, avail, tel) -> np.ndarray:
+    """The ``"jax"`` impl: pad the lanes to their bucket, run the jitted
+    kernel, copy the scores back and slice the padding off."""
     from repro.engine.jax_backend import _require_jax
 
     jax_mod, jnp, _ = _require_jax()
@@ -94,6 +112,9 @@ def eet_scores(
         w_scaled = np.pad(w_scaled, pad)
         avail = np.pad(avail, pad)  # padded lanes: avail False -> inf, sliced off
     fn = _jit_fn((Lp, T), jax_mod)
-    out = np.asarray(fn(jnp.asarray(p_fail), jnp.asarray(wasted),
-                        jnp.asarray(w_scaled), jnp.asarray(avail)))
+    args = (p_fail, wasted, w_scaled, avail)
+    tel.count("fleet_step.cells", Lp * T)
+    tel.count("fleet_step.h2d_bytes", sum(a.nbytes for a in args))
+    out = np.asarray(fn(*(jnp.asarray(a) for a in args)))
+    tel.count("fleet_step.d2h_bytes", out.nbytes)
     return out[:L]
