@@ -6,10 +6,12 @@ while simulating every cell of the grid together:
 
   * **Placement waves.**  Each round's placements (all arrivals, then each
     round's migrations) score one ``(lane, type)`` EET matrix through the
-    :mod:`repro.kernels.fleet_step` op.  The expensive pdf prefix sums are
-    memoized per ``(seed, type, bid, w_bins)`` using the *verbatim* scalar
-    expressions of :func:`repro.core.provision.expected_execution_time`, so
-    scores are IEEE-identical to per-call ``ctx.eet`` / ``algorithm1``.
+    :mod:`repro.kernels.fleet_step` op.  Each ``(seed, type, bid)`` resolves
+    once to a history-pdf slot holding the pdf and its weighted vector
+    ``(k·bin_s + t_r)·f``, and Eq. 8's two prefix sums are memoized per
+    ``(slot, w_bins)``: the same ``np.sum`` over the same floats as
+    :func:`repro.core.provision.expected_execution_time`, so scores are
+    IEEE-identical to per-call ``ctx.eet`` / ``algorithm1``.
   * **Attempt waves.**  All lanes that need an attempt simulated this round
     go through one call per scheme into the shared pure kernels of
     :mod:`repro.engine.kernels` (``_kernel_none`` / ``_kernel_opt`` /
@@ -35,9 +37,12 @@ scenarios to the scalar controller.  Telemetry differences are documented in
 controller's per-event ``tel.event`` stream and per-job ``fleet.place`` /
 ``fleet.migrate`` spans.  Its own phases have spans of their own:
 ``fleet.place_wave`` and ``fleet.sim_wave`` per wave, and ``fleet.replay``
-around phase 2, with the counters ``fleet_batch.rounds`` and
-``fleet_batch.placements`` (outside ``fleet.*``, whose counters are the
-controller's).
+around phase 2, with the counters ``fleet_batch.rounds``,
+``fleet_batch.placements``, and for the placement rows
+``fleet_batch.eet_term_requests`` (prefix terms the waves asked for),
+``fleet_batch.eet_terms`` (terms evaluated: the memo's misses) and
+``fleet_batch.pdf_slots`` (slots built) — all outside ``fleet.*``, whose
+counters are the controller's.
 """
 
 from __future__ import annotations
@@ -112,6 +117,11 @@ class _Memo:
     function of the traces/histories — safe to share and to persist across
     benchmark repeats (which is what makes warm batch runs skip every pdf
     build the controller re-does per cell).
+
+    Eq. 8 reads each history pdf through a *slot* (:meth:`slot`): the pdf
+    array and its weighted vector, built once per ``(seed, type, bid,
+    recovery)``; its prefix terms are memoized per ``(slot, w)`` with ``w``
+    clamped to the pdf's length (:meth:`terms`).
     """
 
     def __init__(self, traces, histories):
@@ -119,8 +129,10 @@ class _Memo:
         self.histories = histories
         self.periods: dict = {}  # (seed, name, bid) -> (A, B) arrays
         self.pdfs: dict = {}  # (seed, name, round(bid,6)) -> FailurePdf (history)
-        self.avail: dict = {}  # (seed, name, bid) -> bool (history ever <= bid)
-        self.eet_terms: dict = {}  # (seed, name, round(bid,6), w_bins) -> (p_fail, wasted)
+        self.slots: dict = {}  # (seed, name, bid, recovery_s) -> slot | None (never <= bid)
+        self.slot_pdf: list = []  # slot -> pdf array f
+        self.slot_wpdf: list = []  # slot -> (k·bin_s + recovery_s)·f
+        self.term_vals: dict = {}  # (slot, min(w_bins, len(f))) -> (p_fail, wasted)
         self.edges: dict = {}  # (seed, name) -> rising-edge times (eval trace)
         self.prices_now: dict = {}  # (seed, t) -> {name: price}
         # assembled placement rows, finished EET score rows, and finished
@@ -162,29 +174,47 @@ class _Memo:
             val = self.pdfs[key] = FailurePdf.from_trace(self.histories[seed][name], bid)
         return val
 
-    def available(self, seed: int, name: str, bid: float) -> bool:
-        """``hist.next_available(bid, 0.0) is not None`` without the scan."""
-        key = (seed, name, bid)
-        val = self.avail.get(key)
-        if val is None:
-            hist = self.histories[seed][name]
-            val = self.avail[key] = bool((hist.prices <= bid).any())
-        return val
-
-    def eet_term(self, seed: int, name: str, bid: float, w_bins: int, recovery_s: float):
-        """The two pdf prefix sums of Eq. 8, computed with the scalar
-        expressions of :func:`expected_execution_time` verbatim (``np.sum``
-        pairwise summation included) and memoized."""
-        key = (seed, name, round(bid, 6), w_bins)
-        val = self.eet_terms.get(key)
-        if val is None:
+    def slot(self, seed: int, name: str, bid: float, recovery_s: float):
+        """The history-pdf slot of ``(seed, name, bid)``, or None when the
+        history never drops to the bid (``hist.next_available(bid, 0.0) is
+        None``: Eq. 8 is inf there, and no pdf is built)."""
+        key = (seed, name, bid, recovery_s)
+        if key in self.slots:
+            return self.slots[key]
+        slot = None
+        if (self.histories[seed][name].prices <= bid).any():
             pdf = self.pdf(seed, name, bid)
-            k = np.arange(len(pdf.pdf))
-            fail_before = pdf.pdf[:w_bins] if w_bins <= len(pdf.pdf) else pdf.pdf
-            p_fail = float(np.sum(fail_before))
-            wasted = float(np.sum((k[: len(fail_before)] * pdf.bin_s + recovery_s) * fail_before))
-            val = self.eet_terms[key] = (p_fail, wasted)
-        return val
+            # w_bins is quantized with the catalog-wide default bin width;
+            # every history pdf is built with it (FailurePdf.from_trace)
+            assert pdf.bin_s == FailurePdf.DEFAULT_BIN_S
+            slot = len(self.slot_pdf)
+            self.slot_pdf.append(pdf.pdf)
+            # element for element the temporary expected_execution_time
+            # builds for any prefix: (k[:w] * bin_s + recovery_s) * f[:w]
+            self.slot_wpdf.append((np.arange(len(pdf.pdf)) * pdf.bin_s + recovery_s) * pdf.pdf)
+        self.slots[key] = slot
+        return slot
+
+    def terms(self, keys) -> tuple[list, int]:
+        """Eq. 8's two pdf prefix sums ``(p_fail, wasted)`` for each ``(slot,
+        w)`` key (``w`` at most the pdf's length), memoized.  Each is
+        ``np.add.reduce`` — the reduction ``np.sum`` runs, pairwise summation
+        included — over the very floats :func:`expected_execution_time` sums.
+        Returns the values and how many were evaluated (the misses)."""
+        vals = []
+        n_new = 0
+        reduce = np.add.reduce
+        for key in keys:
+            val = self.term_vals.get(key)
+            if val is None:
+                slot, w = key
+                val = self.term_vals[key] = (
+                    float(reduce(self.slot_pdf[slot][:w])),
+                    float(reduce(self.slot_wpdf[slot][:w])),
+                )
+                n_new += 1
+            vals.append(val)
+        return vals, n_new
 
     def rising_edges(self, seed: int, name: str) -> np.ndarray:
         key = (seed, name)
@@ -689,19 +719,24 @@ class _BatchFleet:
             WA = np.zeros((len(pend), T))
             WS = np.zeros((len(pend), T))
             AV = np.zeros((len(pend), T), dtype=bool)
+            build: dict = {}  # row key -> (request, bids, w_bins, wave rows)
             for m, (i, _) in enumerate(pend):
                 rq = reqs[i]
                 w_scaled = rq.remaining * self.ratio
                 w_bins = np.maximum(
                     1, np.ceil(w_scaled / FailurePdf.DEFAULT_BIN_S).astype(np.int64)
                 )
+                WS[m] = w_scaled  # only AV-true entries reach a finite score
                 rkey = (rq.cell.seed, sigs[i], feats[i], w_bins[rq.feas].tobytes())
                 row = self.memo.rows.get(rkey)
-                if row is None:
-                    row = self._build_row(rq, bids_rows[i], w_bins)
-                    self.memo.rows[rkey] = row
-                P[m], WA[m], AV[m] = row
-                WS[m] = w_scaled  # only AV-true entries reach a finite score
+                if row is not None:
+                    P[m], WA[m], AV[m] = row
+                elif rkey in build:
+                    build[rkey][3].append(m)
+                else:
+                    build[rkey] = (rq, bids_rows[i], w_bins, [m])
+            if build:
+                self._build_rows(build, P, WA, AV)
             eet = fleet_ops.eet_scores(P, WA, WS, AV, impl=self.score_impl)
             for m, (i, skey) in enumerate(pend):
                 scores[i] = self.score_rows[skey] = eet[m]
@@ -711,27 +746,47 @@ class _BatchFleet:
             out[i] = pls
         return out
 
-    def _build_row(self, rq, bids, w_bins):
-        """One request's ``(p_fail, wasted, avail)`` columns over the catalog
-        — the cache-miss path of :meth:`_place_wave`."""
-        T = len(self.types)
-        p_row = np.zeros(T)
-        wa_row = np.zeros(T)
-        av_row = np.zeros(T, dtype=bool)
-        seed = rq.cell.seed
-        for t in rq.feas:
-            b = bids[t]
-            if not self.memo.available(seed, self.names[t], b):
-                continue  # AV False -> inf (never below bid in history)
-            av_row[t] = True
-            pdf = self.memo.pdf(seed, self.names[t], b)
-            # w_bins was quantized with the catalog-wide default bin width;
-            # every history pdf is built with it (FailurePdf.from_trace)
-            assert pdf.bin_s == FailurePdf.DEFAULT_BIN_S
-            p_row[t], wa_row[t] = self.memo.eet_term(
-                seed, self.names[t], b, int(w_bins[t]), self.params.t_r
-            )
-        return p_row, wa_row, av_row
+    def _build_rows(self, build, P, WA, AV):
+        """Fill a wave's missing ``(p_fail, wasted, avail)`` rows — the
+        cache-miss path of :meth:`_place_wave` — and memoize them.
+
+        ``build`` maps each missing row key to its request, bids, ``w_bins``
+        and the wave rows that share it.  Every feasible type resolves to
+        its pdf slot, the wave's ``(slot, w)`` terms are evaluated once each
+        (:meth:`_Memo.terms`), and the matrices are filled by index.  A
+        ``w_bins`` past the pdf's end sums the whole pdf, as in
+        :func:`expected_execution_time`, so ``w`` is clamped to its length.
+        """
+        memo = self.memo
+        t_r = self.params.t_r
+        n_slots = len(memo.slot_pdf)
+        ms, ts, keys = [], [], []
+        for rq, bids, w_bins, rows in build.values():
+            m = rows[0]
+            seed = rq.cell.seed
+            wl = w_bins.tolist()
+            for t in rq.feas:
+                slot = memo.slot(seed, self.names[t], bids[t], t_r)
+                if slot is None:
+                    continue  # AV False -> inf (never below bid in history)
+                ms.append(m)
+                ts.append(t)
+                keys.append((slot, min(wl[t], len(memo.slot_pdf[slot]))))
+        vals, n_new = memo.terms(keys)
+        if keys:
+            pw = np.asarray(vals)
+            P[ms, ts] = pw[:, 0]
+            WA[ms, ts] = pw[:, 1]
+            AV[ms, ts] = True
+        for rkey, (_, _, _, rows) in build.items():
+            m = rows[0]
+            row = memo.rows[rkey] = (P[m].copy(), WA[m].copy(), AV[m].copy())
+            for other in rows[1:]:
+                P[other], WA[other], AV[other] = row
+        tel = obs.current()
+        tel.count("fleet_batch.eet_term_requests", len(keys))
+        tel.count("fleet_batch.eet_terms", n_new)
+        tel.count("fleet_batch.pdf_slots", len(memo.slot_pdf) - n_slots)
 
     def _walk(self, rq, bids, row):
         """One request's policy walk — expression-for-expression the scalar
@@ -988,6 +1043,9 @@ class _BatchFleet:
 
     def run(self):
         tel = obs.current()
+        for name in ("fleet_batch.eet_term_requests", "fleet_batch.eet_terms",
+                     "fleet_batch.pdf_slots"):
+            tel.count(name, 0)  # present, at 0, when every row comes from the memo
         self._arrivals()
         tel.count("fleet_batch.rounds")
         while self._round():
@@ -1276,9 +1334,10 @@ def run_fleet_batch(
     controller sweep's cell order (seed-major, then margin, then policy) —
     each result ``==`` what ``FleetController.run`` produces for that cell.
 
-    ``memo`` carries the derived-input caches (period rows, pdf terms, ADAPT
-    tables) across calls: pass the same instance for repeat runs of the same
-    traces (as the benchmark's warm runs do) to skip every rebuild.
+    ``memo`` carries the derived-input caches (period rows, pdf slots and
+    their prefix terms, placement rows, ADAPT tables) across calls: pass the
+    same instance for repeat runs of the same traces (as the benchmark's warm
+    runs do) to skip every rebuild.
     ``score_impl`` selects the EET scoring backend (``"numpy"`` | ``"jax"``).
     """
     if memo is None:
