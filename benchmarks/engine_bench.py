@@ -1,9 +1,9 @@
-"""Engine throughput: reference (scalar) vs batch (SoA NumPy) vs jax/pallas.
+"""Engine throughput: reference (scalar) vs batch (SoA NumPy) vs jax.
 
 Usage::
 
     PYTHONPATH=src python benchmarks/engine_bench.py --quick \
-        [--min-speedup 10] [--require-jax-ge-batch] [--profile] [--pallas] \
+        [--min-speedup 10] [--require-jax-ge-batch] [--profile] \
         [--check-trend] [--overhead-gate 5]
 
 Evaluates the §VII-style grid on every available backend, verifies exact
@@ -33,13 +33,10 @@ survive across builds.
 (grid build, per-scheme simulation vs billing, scalar fill).
 
 The jax backend is benchmarked when jax is importable (skipped otherwise, or
-with ``--skip-jax``).  The Pallas sweep kernel gets a ``pallas`` row when
-``--pallas`` asks for it (interpreter mode — exact, but far too slow for the
-CI grid, hence opt-in; its CI coverage is the interpret-mode parity suite in
-``tests/kernels/test_spot_sweep.py``).  Every candidate
-backend gets one untimed warm-up run (allocator pools, jit compilation)
-before ``--repeats`` timed runs, of which the fastest is reported — the gates
-measure steady-state throughput, not cold-start noise.  Wall times are
+with ``--skip-jax``).  Every candidate backend gets one untimed warm-up
+run (allocator pools, jit compilation) before ``--repeats`` timed runs, of
+which the fastest is reported — the gates measure steady-state throughput,
+not cold-start noise.  Wall times are
 simulation-only (all backends share identical trace materialization, which is
 excluded by ``EngineResult.wall_s``).
 """
@@ -57,7 +54,6 @@ from repro import configure_logging, obs
 from repro.core import catalog
 from repro.engine import (
     BID_LIMITED_SCHEMES,
-    PallasEngine,
     ReferenceEngine,
     Scenario,
     get_engine,
@@ -251,12 +247,6 @@ def main(argv: list[str] | None = None) -> int:
     )
     ap.add_argument("--skip-jax", action="store_true", help="do not benchmark the jax backend")
     ap.add_argument(
-        "--pallas",
-        action="store_true",
-        help="benchmark the Pallas sweep kernel (interpreter mode: exact but "
-        "very slow — use a small grid)",
-    )
-    ap.add_argument(
         "--profile",
         action="store_true",
         help="print per-scheme and per-phase (sim vs billing) timing breakdowns",
@@ -311,11 +301,6 @@ def main(argv: list[str] | None = None) -> int:
     backends = ["batch"]
     if not args.skip_jax and have_jax():
         backends.append("jax")
-        if args.pallas:
-            backends.append("pallas")
-    elif args.pallas:
-        log.error("FAIL: --pallas needs jax available and not --skip-jax")
-        return 2
 
     record = {
         "grid": {
@@ -341,8 +326,7 @@ def main(argv: list[str] | None = None) -> int:
 
     speedups: dict[str, float] = {}
     for name in backends:
-        # the Pallas kernel runs interpreted, and only when asked for by name
-        engine = PallasEngine(interpret=True) if name == "pallas" else get_engine(name)
+        engine = get_engine(name)
         # one untimed warm-up per candidate (allocator pools, jit compile):
         # the timed repeats then measure steady-state throughput
         engine.run(scenario)

@@ -19,9 +19,6 @@ interchangeable engine backend:
     billing inputs accumulated on-device; explicit opt-in via
     ``engine="jax"``, the same exact-parity contract on CPU (a TPU emulates
     float64 and is not exact), >= batch throughput there (CI-gated).
-  * :class:`PallasEngine` — the same step as a fused Pallas TPU kernel, in
-    interpreter mode only (``PallasEngine(interpret=True)``); the float64
-    kernel does not compile natively, so a native one raises.
   * :func:`run` / :func:`run_fleet` — the one-call entry points.
 
 This is the *only* sweep surface: the long-deprecated shims
@@ -41,7 +38,7 @@ from repro.engine.base import (
 )
 from repro.engine.batch import BatchEngine
 from repro.engine.fleetgrid import FleetGridResult, policy_registry, resolve_policies, run_fleet
-from repro.engine.jax_backend import JaxEngine, PallasEngine, have_jax
+from repro.engine.jax_backend import JaxEngine, have_jax
 from repro.engine.parity import (
     CellMismatch,
     ParityReport,
@@ -63,7 +60,6 @@ __all__ = [
     "PARITY_FIELDS",
     "BatchEngine",
     "JaxEngine",
-    "PallasEngine",
     "have_jax",
     "CellMismatch",
     "Engine",

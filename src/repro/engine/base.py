@@ -2,7 +2,7 @@
 
 An :class:`Engine` consumes a :class:`~repro.engine.scenario.Scenario` and
 returns an :class:`EngineResult` — per-cell outcome arrays shaped
-``(n_markets, n_bids, n_schemes)``.  Two interchangeable backends ship:
+``(n_markets, n_bids, n_schemes)``.  Three interchangeable backends ship:
 
   * :class:`~repro.engine.reference.ReferenceEngine` — wraps the scalar
     event loop of :func:`repro.core.simulator.simulate`; the semantic anchor.
@@ -15,10 +15,6 @@ returns an :class:`EngineResult` — per-cell outcome arrays shaped
     spot-sweep program (one jit compile for the whole scheme set) on
     ``jax.numpy`` with x64 (``engine="jax"``); same parity contract on CPU,
     not on a TPU (emulated float64).
-  * :class:`~repro.engine.jax_backend.PallasEngine` — the same step as a
-    fused Pallas TPU kernel, in interpreter mode only
-    (``PallasEngine(interpret=True)``); the float64 kernel does not compile
-    natively, so a native one raises.
 
 ``run(scenario)`` is the one-call surface; ``engine="auto"`` picks the batch
 backend on every platform (see :func:`get_engine` for why not the jax
@@ -66,7 +62,7 @@ class PhaseTimings:
     engine: str
     total_s: float
     grid_s: float = 0.0  # period grid + ADAPT tables (cache misses only)
-    sim_s: float = 0.0  # fused one-compile sim phase (jax/pallas)
+    sim_s: float = 0.0  # fused one-compile sim phase (jax)
     scalar_s: float = 0.0  # scalar event-loop phase (reference engine)
     impl: str | None = None  # spot_sweep implementation label, when applicable
     per_scheme: Mapping[str, SchemePhases] = dataclasses.field(default_factory=dict)
@@ -261,8 +257,8 @@ class Engine(Protocol):
 
 
 def get_engine(name: str = "auto") -> Engine:
-    """Resolve an engine by name: ``"reference"``, ``"batch"``, ``"jax"``,
-    ``"pallas"`` or ``"auto"``.
+    """Resolve an engine by name: ``"reference"``, ``"batch"``, ``"jax"`` or
+    ``"auto"``.
 
     ``"auto"`` is the batch backend on every platform, TPU included: it is
     held ``==`` to the reference on every scheme, and the jax backend is not
@@ -271,11 +267,6 @@ def get_engine(name: str = "auto") -> Engine:
     tables already round on the way to the device and near-tie decisions
     flip; ``chip_smoke.py`` prints the disagreement.  ``"auto"`` moves to a
     device program once one is exact there (an integer substrate).
-
-    ``"pallas"`` is the native Pallas sweep kernel, which the float64 kernel
-    cannot be compiled into, so it raises :class:`NotImplementedError`; its
-    interpreter mode is asked for by constructing
-    ``PallasEngine(interpret=True)`` and passing the engine itself.
 
     Backend choice is explicit: ``"jax"`` raises :class:`ImportError` with an
     install hint when jax is missing rather than silently running on NumPy.
@@ -291,13 +282,7 @@ def get_engine(name: str = "auto") -> Engine:
         from repro.engine.jax_backend import JaxEngine
 
         return JaxEngine()
-    if name == "pallas":
-        from repro.engine.jax_backend import PallasEngine
-
-        return PallasEngine()
-    raise ValueError(
-        f"unknown engine {name!r}; expected auto|batch|reference|jax|pallas"
-    )
+    raise ValueError(f"unknown engine {name!r}; expected auto|batch|reference|jax")
 
 
 def run(scenario: Scenario, engine: str | Engine = "auto") -> EngineResult:

@@ -50,13 +50,13 @@ from repro.engine.kernels import (
     _kernel_windows,
     acc_lease_tick,
 )
-from repro.engine.scenario import BATCHED_SCHEMES, MarketCell, Scenario
+from repro.engine.scenario import MarketCell, Scenario
 from repro.obs import telemetry as obs
 
 #: Per-scenario cache of the derived simulation inputs (period grid, ADAPT
 #: decision tables) shared by *every* array backend in the process: running
-#: the same Scenario object on batch, then jax, then pallas builds the grid
-#: and tables exactly once.  Keys are weak — the cache dies with the scenario.
+#: the same Scenario object on batch, then jax builds the grid and tables
+#: exactly once.  Keys are weak — the cache dies with the scenario.
 _SCENARIO_CACHE: "weakref.WeakKeyDictionary[Scenario, dict]" = weakref.WeakKeyDictionary()
 
 
@@ -80,18 +80,17 @@ def grid_and_tables(
 
 
 def run_batched(scenario: Scenario, engine_name: str, run_schemes) -> EngineResult:
-    """Shared driver for the array backends (batch, jax, pallas).
+    """Shared driver for the array backends (batch, jax).
 
     Materializes the market, resolves the cached period grid + ADAPT decision
     tables, and dispatches the whole scheme set to ``run_schemes(schemes,
     grid, scenario, adapt_tables)`` — one call, so a backend may evaluate
-    every scheme in a single compiled program.  Every scheme is batched now
-    (``BATCHED_SCHEMES`` covers ACC too); the scalar-fill branch survives
-    only as a guard should a scheme ever leave the batched set again.  The
-    backends can never drift in their orchestration, only in their kernels.
+    every scheme in a single compiled program.  Every scheme is batched
+    (``BATCHED_SCHEMES`` covers ACC too).  The backends can never drift in
+    their orchestration, only in their kernels.
 
     Every phase is timed as a telemetry span (``grid`` / ``sim`` / ``bill``
-    / ``scalar`` under one ``engine.run`` root); the span tree lands in the
+    under one ``engine.run`` root); the span tree lands in the
     active :class:`~repro.obs.telemetry.Telemetry` collector when there is
     one — a throwaway local collector otherwise — and is folded into the
     typed :class:`~repro.engine.base.PhaseTimings` on
@@ -106,30 +105,21 @@ def run_batched(scenario: Scenario, engine_name: str, run_schemes) -> EngineResu
     res = empty_result(scenario, markets, engine_name)
 
     with obs.activate(tel), tel.span("engine.run", engine=engine_name) as root:
-        batched = [s for s in scenario.schemes if s in BATCHED_SCHEMES]
-        fallback = [s for s in scenario.schemes if s not in BATCHED_SCHEMES]
-
-        if batched:
-            with tel.span("grid"):
-                grid, adapt_tables = grid_and_tables(scenario, markets, Scheme.ADAPT in batched)
-            outs, _info = run_schemes(tuple(batched), grid, scenario, adapt_tables)
-            M, B = len(markets), len(scenario.bids)
-            for scheme, out in outs.items():
-                s = scenario.schemes.index(scheme)
-                res.completed[:, :, s] = out["completed"].reshape(M, B)
-                res.completion_time[:, :, s] = out["completion_time"].reshape(M, B)
-                res.cost[:, :, s] = out["cost"].reshape(M, B)
-                res.n_checkpoints[:, :, s] = out["n_checkpoints"].reshape(M, B)
-                res.n_kills[:, :, s] = out["n_kills"].reshape(M, B)
-                res.work_lost_s[:, :, s] = out["work_lost_s"].reshape(M, B)
-                if "n_self_terminations" in out:
-                    res.n_self_terminations[:, :, s] = out["n_self_terminations"].reshape(M, B)
-
-        if fallback:  # pragma: no cover - BATCHED_SCHEMES covers every scheme
-            from repro.engine.reference import scalar_fill
-
-            with tel.span("scalar", schemes=[s.value for s in fallback]):
-                scalar_fill(scenario, markets, res, fallback)
+        schemes = tuple(scenario.schemes)
+        with tel.span("grid"):
+            grid, adapt_tables = grid_and_tables(scenario, markets, Scheme.ADAPT in schemes)
+        outs, _info = run_schemes(schemes, grid, scenario, adapt_tables)
+        M, B = len(markets), len(scenario.bids)
+        for scheme, out in outs.items():
+            s = scenario.schemes.index(scheme)
+            res.completed[:, :, s] = out["completed"].reshape(M, B)
+            res.completion_time[:, :, s] = out["completion_time"].reshape(M, B)
+            res.cost[:, :, s] = out["cost"].reshape(M, B)
+            res.n_checkpoints[:, :, s] = out["n_checkpoints"].reshape(M, B)
+            res.n_kills[:, :, s] = out["n_kills"].reshape(M, B)
+            res.work_lost_s[:, :, s] = out["work_lost_s"].reshape(M, B)
+            if "n_self_terminations" in out:
+                res.n_self_terminations[:, :, s] = out["n_self_terminations"].reshape(M, B)
 
     res.wall_s = time.perf_counter() - t0
     res.timings = PhaseTimings.from_span(root, engine_name, res.wall_s)

@@ -1,4 +1,4 @@
-"""JAX backends: the fused spot-sweep programs on the (type × bid × seed) grid.
+"""JAX backend: the fused spot-sweep program on the (type × bid × seed) grid.
 
 :class:`JaxEngine` evaluates every batched scheme of a scenario as **one**
 jit-compiled program: the multi-scheme ``lax.scan`` built by
@@ -10,13 +10,6 @@ billing inputs — per-period run records and the ``n_kills`` tally —
 accumulate on-device in the scan carry/ys; the host only folds the records
 through the vectorized NumPy biller shared with
 :class:`~repro.engine.batch.BatchEngine`.
-
-:class:`PallasEngine` runs the same step as the fused Pallas kernel
-(``repro.kernels.spot_sweep.kernel.sweep_pallas``) in interpreter mode, and
-only when the caller asks for it (``PallasEngine(interpret=True)``): the
-float64 kernel does not compile natively for the TPU
-(:data:`~repro.kernels.spot_sweep.kernel.NATIVE_UNSUPPORTED`), so a native
-``PallasEngine`` raises instead of interpreting behind the caller's back.
 
 The per-step float expressions are the shared pure kernels of
 :mod:`repro.engine.kernels` called with ``xp=jax.numpy`` (x64 enabled):
@@ -104,8 +97,6 @@ class JaxEngine:
     the trace count)."""
 
     name = "jax"
-    #: which spot_sweep implementation this engine requests
-    impl: str = "scan"
 
     def __init__(self):
         self._jax, self._jnp, self._lax = _require_jax()
@@ -116,28 +107,4 @@ class JaxEngine:
     def _run_schemes(self, schemes, grid, scenario, adapt_tables):
         from repro.kernels.spot_sweep import ops as sweep_ops
 
-        return sweep_ops.spot_sweep_grid(
-            schemes, grid, scenario, adapt_tables, impl=self.impl
-        )
-
-
-class PallasEngine(JaxEngine):
-    """The fused Pallas lockstep kernel as an engine backend.
-
-    Interpreter mode must be asked for (``interpret=True``): exact, but
-    orders of magnitude slower than the jitted scan, so it is meant for
-    parity verification and kernel development, not throughput.  Without it
-    the engine would have to compile the kernel natively, which the float64
-    kernel cannot do on the TPU, so construction raises
-    :class:`NotImplementedError` naming why (porting the kernel off float64
-    is tracked in ROADMAP.md)."""
-
-    name = "pallas"
-
-    def __init__(self, interpret: bool = False):
-        if not interpret:
-            from repro.kernels.spot_sweep.kernel import NATIVE_UNSUPPORTED
-
-            raise NotImplementedError(NATIVE_UNSUPPORTED)
-        super().__init__()
-        self.impl = "interpret"
+        return sweep_ops.spot_sweep_grid(schemes, grid, scenario, adapt_tables)

@@ -109,12 +109,11 @@ def period_step_masked(xp, scheme, state, a, b, valid, horizon, t_r, run_kernel)
     driver's index compression (masked lanes cost nothing under vmap-style
     array execution, and compression would make traced shapes dynamic).
 
-    The shared per-period orchestration of the fused sweep programs (the
-    jitted ``lax.scan`` and the Pallas kernel in
-    :mod:`repro.kernels.spot_sweep`): enter the period, consume too-short
-    availability windows, dispatch the scheme kernel via ``run_kernel(go, a,
-    b, start_work, saved)``, then fold completions / kills / checkpoint counts
-    into the carried state.  ``state`` is the 7-tuple ``(saved, done,
+    The per-period orchestration of the fused sweep program (the jitted
+    ``lax.scan`` in :mod:`repro.kernels.spot_sweep`): enter the period,
+    consume too-short availability windows, dispatch the scheme kernel via
+    ``run_kernel(go, a, b, start_work, saved)``, then fold completions /
+    kills / checkpoint counts into the carried state.  ``state`` is the 7-tuple ``(saved, done,
     comp_time, n_ckpt, work_lost, has_run, n_kills)`` — ``n_kills``
     accumulates on-device (one count per non-user-terminated recorded run,
     exactly the billing-side tally).  Returns ``(state, (rec_exists, rec_end,
@@ -494,8 +493,8 @@ def adapt_tick(xp, state, a, b, work_s, t_c, t_r, interval, flat, off, top, bin_
     """One period-synchronized ADAPT tick for every in-loop cell.
 
     ``state = (in_loop, t, work, sv, next_dec, done_now, done_at, ckpt_add)``.
-    A thin bookkeeping wrapper over :func:`adapt_tick_core`, shared by the
-    ``_kernel_adapt`` host loop and the JAX/Pallas ``lax.while_loop`` body.
+    A thin bookkeeping wrapper over :func:`adapt_tick_core` for the
+    ``_kernel_adapt`` host loop.
     """
     in_loop, t, work, sv, next_dec, done_now, done_at, ckpt_add = state
     live, t, work, sv, next_dec, d_at, fin, ck, _ = adapt_tick_core(

@@ -1,9 +1,8 @@
 """Reference backend: the scalar event loop, cell by cell.
 
 Wraps :func:`repro.core.simulator.simulate` over every (market, bid, scheme)
-cell of a Scenario.  Slow but semantically canonical — the batch backend is
-defined by agreeing with this one (see :mod:`repro.engine.parity`), and
-borrows :func:`scalar_fill` for the schemes it cannot lower (ADAPT/ACC).
+cell of a Scenario.  Slow but semantically canonical — the array backends
+are defined by agreeing with this one (see :mod:`repro.engine.parity`).
 
 ADAPT failure pdfs are cached per (market, bid), mirroring the pdf cache the
 legacy sweep loop kept, so the reference engine is not gratuitously slower

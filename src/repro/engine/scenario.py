@@ -17,7 +17,7 @@ Capacity-constrained markets plug in exactly here (see
 pool, ``demand`` is the depth of the co-located foreground block a cell's
 job is the marginal replica of, and materialization replaces each exogenous
 trace with its auction-cleared view — so every backend (reference, batch,
-jax, pallas) honors preemption-by-outbid through the one out-of-bid rule it
+jax) honors preemption-by-outbid through the one out-of-bid rule it
 already implements, bit-identically.  ``capacity=None`` (the default) keeps
 today's infinitely deep market, byte for byte.
 """

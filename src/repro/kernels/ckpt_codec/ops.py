@@ -6,22 +6,9 @@ import jax
 
 from repro.kernels.ckpt_codec import ref
 
-_FORCE_IMPL: str | None = None
-
-
-def set_impl(impl: str | None) -> None:
-    global _FORCE_IMPL
-    _FORCE_IMPL = impl
-
-
-def _default_impl() -> str:
-    if _FORCE_IMPL is not None:
-        return _FORCE_IMPL
-    return "pallas" if jax.default_backend() == "tpu" else "ref"
-
 
 def quantize(x, block: int = ref.BLOCK, impl: str | None = None):
-    impl = impl or _default_impl()
+    impl = impl or ("pallas" if jax.default_backend() == "tpu" else "ref")
     if impl in ("pallas", "interpret"):
         from repro.kernels.ckpt_codec import kernel
 

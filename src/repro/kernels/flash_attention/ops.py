@@ -13,19 +13,6 @@ import jax
 
 from repro.kernels.flash_attention import ref
 
-_FORCE_IMPL: str | None = None  # "pallas" | "interpret" | "ref" (tests/debug)
-
-
-def set_impl(impl: str | None) -> None:
-    global _FORCE_IMPL
-    _FORCE_IMPL = impl
-
-
-def _default_impl() -> str:
-    if _FORCE_IMPL is not None:
-        return _FORCE_IMPL
-    return "pallas" if jax.default_backend() == "tpu" else "ref"
-
 
 def flash_attention(
     q,
@@ -39,7 +26,7 @@ def flash_attention(
     q_offset: int = 0,
     impl: str | None = None,
 ):
-    impl = impl or _default_impl()
+    impl = impl or ("pallas" if jax.default_backend() == "tpu" else "ref")
     sq, sk = q.shape[1], k.shape[1]
     q_block = min(q_block, sq)
     kv_block = min(kv_block, sk)

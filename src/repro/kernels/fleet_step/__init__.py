@@ -1,6 +1,6 @@
 """fleet_step kernel triad: the fleet engine's per-wave EET scoring op.
 
-Same layout as :mod:`repro.kernels.spot_sweep`:
+Its files:
 
   * ``ref.py``    — NumPy reference (`eet_scores_numpy`), bit-exact vs the
     scalar :func:`repro.core.provision.expected_execution_time` combine;
@@ -9,6 +9,6 @@ Same layout as :mod:`repro.kernels.spot_sweep`:
     retrace accounting (scope ``"fleet_step"``).
 """
 
-from repro.kernels.fleet_step.ops import eet_scores, set_impl, trace_count
+from repro.kernels.fleet_step.ops import eet_scores, trace_count
 
-__all__ = ["eet_scores", "set_impl", "trace_count"]
+__all__ = ["eet_scores", "trace_count"]

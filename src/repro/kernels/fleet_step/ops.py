@@ -21,22 +21,11 @@ import numpy as np
 from repro.obs import retrace
 from repro.obs import telemetry as obs
 
-_FORCE_IMPL: str | None = None
-
 #: retrace-registry scope for the jitted EET kernel (detail = padded shape)
 TRACE_SCOPE = "fleet_step"
 
 #: jitted kernel per padded (lanes, types) shape; process-wide
 _JIT_CACHE: dict[tuple[int, int], object] = {}
-
-
-def set_impl(impl: str | None) -> None:
-    global _FORCE_IMPL
-    _FORCE_IMPL = impl
-
-
-def _default_impl() -> str:
-    return _FORCE_IMPL if _FORCE_IMPL is not None else "numpy"
 
 
 def trace_count(shape: tuple[int, int]) -> int:
@@ -71,7 +60,7 @@ def eet_scores(
     wasted: np.ndarray,
     w_scaled: np.ndarray,
     avail: np.ndarray,
-    impl: str | None = None,
+    impl: str = "numpy",
 ) -> np.ndarray:
     """Eq. 8 scores for one ``(lane, type)`` wave; see :mod:`.ref`.
 
@@ -81,8 +70,6 @@ def eet_scores(
     (lanes × types as scored, padded lanes included); the ``"jax"`` impl
     also counts the bytes it copies to the device and back in
     ``fleet_step.h2d_bytes`` / ``fleet_step.d2h_bytes``."""
-    if impl is None:
-        impl = _default_impl()
     if impl not in ("numpy", "jax"):
         raise ValueError(f"unknown fleet_step impl {impl!r}")
     tel = obs.current()

@@ -6,22 +6,9 @@ import jax
 
 from repro.kernels.rglru_scan import ref
 
-_FORCE_IMPL: str | None = None
-
-
-def set_impl(impl: str | None) -> None:
-    global _FORCE_IMPL
-    _FORCE_IMPL = impl
-
-
-def _default_impl() -> str:
-    if _FORCE_IMPL is not None:
-        return _FORCE_IMPL
-    return "pallas" if jax.default_backend() == "tpu" else "ref"
-
 
 def rglru_scan(log_a, gated_x, h0=None, *, chunk: int = 256, impl: str | None = None):
-    impl = impl or _default_impl()
+    impl = impl or ("pallas" if jax.default_backend() == "tpu" else "ref")
     if impl in ("pallas", "interpret"):
         from repro.kernels.rglru_scan import kernel
 

@@ -1,21 +1,15 @@
-"""Spot-sweep op: backend dispatch for the fused (type × bid × seed) sweep.
+"""Spot-sweep op: the fused (type × bid × seed) sweep on the device.
 
 ``spot_sweep_grid`` evaluates every batched scheme of a scenario over a
-pre-built period grid and returns the same per-scheme output dicts as the
-NumPy driver, whatever the implementation:
+pre-built period grid with the one-compile multi-scheme ``lax.scan``
+program (:func:`repro.kernels.spot_sweep.kernel.build_sweep_scan`), jitted
+and cached per scheme set, and returns the same per-scheme output dicts as
+the NumPy driver :func:`repro.engine.batch.run_schemes_numpy`, its
+reference.
 
-  * ``"ref"`` — the NumPy lockstep driver in :mod:`repro.engine.batch`
-    (the triad's bit-exact reference; no jax required).
-  * ``"scan"`` — the one-compile multi-scheme ``lax.scan`` program
-    (:func:`repro.kernels.spot_sweep.kernel.build_sweep_scan`), jitted and
-    cached per scheme set; the default, on the TPU as elsewhere.
-  * ``"interpret"`` — the fused Pallas kernel in interpreter mode (CPU
-    parity suite; slow, test-sized grids only).  It has no native impl:
-    see :data:`repro.kernels.spot_sweep.kernel.NATIVE_UNSUPPORTED`.
-
-Device impls simulate on-device (states *and* per-period run records — the
-billing inputs — accumulate in the program) and share the vectorized NumPy
-biller with the batch backend, so costs are bit-identical across every impl.
+The program simulates on-device (states *and* per-period run records — the
+billing inputs — accumulate in it) and shares the vectorized NumPy biller
+with the batch backend, so costs are bit-identical where the simulation is.
 """
 
 from __future__ import annotations
@@ -26,22 +20,11 @@ from repro.core.schemes import Scheme
 from repro.obs import retrace
 from repro.obs import telemetry as obs
 
-_FORCE_IMPL: str | None = None
-
 #: retrace-registry scope for the fused sweep programs (detail = scheme values)
 TRACE_SCOPE = "spot_sweep"
 
 #: jitted scan program per scheme set; shared by every engine in the process
 _SCAN_CACHE: dict[tuple, object] = {}
-
-
-def set_impl(impl: str | None) -> None:
-    global _FORCE_IMPL
-    _FORCE_IMPL = impl
-
-
-def _default_impl() -> str:
-    return _FORCE_IMPL if _FORCE_IMPL is not None else "scan"
 
 
 def trace_count(schemes) -> int:
@@ -71,20 +54,17 @@ def _scan_fn(schemes, jax_mod):
     return fn
 
 
-def _edge_inputs(grid, t_r):
-    """Per-cell EDGE sweep inputs ``(edges_flat, edge_base, edge_n, ptr0)``
-    — the one place the per-market edge arrays expand to the cell axis."""
-    flat, base_m, n_m = grid.edges()
-    m_of = np.arange(grid.n_cells) // grid.n_bids
-    return flat, base_m[m_of], n_m[m_of], grid.edge_ptr0(t_r)
-
-
 def scan_arrays(grid, need_edge, need_adapt, t_r, adapt_tables) -> dict:
     """Host arrays of the scan program's array arguments, by keyword."""
     out = {"A_T": grid.A.T, "B_T": grid.B.T, "valid_T": grid.valid.T, "horizon": grid.horizon}
     if need_edge:
-        flat, base, n, ptr0 = _edge_inputs(grid, t_r)
-        out.update(edges_flat=flat, edge_base=base, edge_n=n, ptr0_T=ptr0.T)
+        # the per-market edge arrays expanded to the cell axis
+        flat, base_m, n_m = grid.edges()
+        m_of = np.arange(grid.n_cells) // grid.n_bids
+        out.update(
+            edges_flat=flat, edge_base=base_m[m_of], edge_n=n_m[m_of],
+            ptr0_T=grid.edge_ptr0(t_r).T,
+        )
     if need_adapt:
         out.update(
             tab_flat=adapt_tables.flat, tab_off=adapt_tables.off, tab_top=adapt_tables.top
@@ -139,37 +119,28 @@ def _device_arrays(grid, jnp, need_edge, need_adapt, t_r, adapt_tables, tel):
     return cache["arrays"]
 
 
-def spot_sweep_grid(
-    schemes,
-    grid,
-    scenario,
-    adapt_tables=None,
-    impl: str | None = None,
-    block_c: int = 256,
-):
+def spot_sweep_grid(schemes, grid, scenario, adapt_tables=None):
     """Evaluate ``schemes`` over a :class:`~repro.engine.batch._PeriodGrid`.
 
     Returns ``(outs, info)``: ``outs`` maps each scheme to the standard
     output dict (``completed`` / ``completion_time`` / ``cost`` /
     ``n_checkpoints`` / ``n_kills`` / ``work_lost_s``), ``info`` carries the
-    resolved ``impl`` label.  The sim vs billing phase split is recorded as
+    ``impl`` label ``"scan"``.  The sim vs billing phase split is recorded as
     telemetry spans (``sim`` with an ``impl`` attr, ``bill`` per scheme) on
     the active collector — :class:`repro.engine.base.PhaseTimings` folds
-    them for the benchmark's ``--profile`` view.
+    them for the benchmark's ``--profile`` view.  The scan's phases are
+    child spans of ``sim``: ``sim.inputs`` and ``sim.h2d`` (on a device-copy
+    miss), ``sim.device`` (the program, from dispatch until its first output
+    is on the host, so also the copies up still in flight) and ``sim.fetch``
+    (the other copies down; all of them counted in ``sweep.d2h_bytes``).
     """
+    impl = "scan"
     schemes = tuple(schemes)
-    if impl is None:
-        impl = _default_impl()
-    if impl == "ref":
-        from repro.engine.batch import run_schemes_numpy
-
-        return run_schemes_numpy(schemes, grid, scenario, adapt_tables)
-
     tel = obs.current()
     outs: dict[Scheme, dict] = {}
     if Scheme.ACC in schemes:
         # ACC is not period-structured (host-side seek/lease state machine):
-        # every device impl routes it to the NumPy driver and fuses the rest.
+        # it runs on the NumPy driver and the program fuses the rest.
         # A pure-ACC scheme set never touches jax at all.
         from repro.engine.batch import _run_acc
 
@@ -191,10 +162,23 @@ def spot_sweep_grid(
     S = len(schemes)
 
     with tel.span("sim", impl=impl):
-        finals, recs_np = _run_device(
-            impl, schemes, grid, scenario, adapt_tables, jax_mod, jnp,
-            need_edge, need_adapt, delta, S, block_c, tel,
+        kwargs = scan_scalars(scenario, need_adapt, adapt_tables)
+        kwargs.update(
+            _device_arrays(grid, jnp, need_edge, need_adapt, params.t_r, adapt_tables, tel)
         )
+        with tel.span("sim.device"):
+            pairs = _scan_fn(schemes, jax_mod)(**kwargs)
+            # copying one output down waits for the whole program, as the
+            # fetch below would; the array keeps that host copy
+            np.asarray(pairs[0][0][1])
+        with tel.span("sim.fetch"):
+            finals = [
+                # state = (saved, done, comp_time, n_ckpt, work_lost, has_run, n_kills)
+                tuple(np.asarray(pairs[si][0][j]) for j in (1, 2, 3, 4, 6))
+                for si in range(S)
+            ]
+            recs_np = [tuple(np.asarray(x) for x in pairs[si][1]) for si in range(S)]  # (P, C)
+        tel.count("sweep.d2h_bytes", sum(a.nbytes for f in finals + recs_np for a in f))
 
     for si, scheme in enumerate(schemes):
         with tel.span("bill", scheme=scheme.value):
@@ -213,63 +197,3 @@ def spot_sweep_grid(
                 "work_lost_s": work_lost,
             }
     return outs, {"impl": impl}
-
-
-def _run_device(
-    impl, schemes, grid, scenario, adapt_tables, jax_mod, jnp,
-    need_edge, need_adapt, delta, S, block_c, tel,
-):
-    """Dispatch the fused device sweep; returns per-scheme final states and
-    run records as host arrays.  The scan's phases are timed as child spans
-    of ``sim``: ``sim.inputs`` and ``sim.h2d`` (on a device-copy miss),
-    ``sim.device`` (the program, from dispatch until its first output is on
-    the host, so also the copies up still in flight) and ``sim.fetch`` (the
-    other copies down; all of them counted in ``sweep.d2h_bytes``)."""
-    params = scenario.params
-    if impl == "scan":
-        kwargs = scan_scalars(scenario, need_adapt, adapt_tables)
-        kwargs.update(
-            _device_arrays(grid, jnp, need_edge, need_adapt, params.t_r, adapt_tables, tel)
-        )
-        with tel.span("sim.device"):
-            pairs = _scan_fn(schemes, jax_mod)(**kwargs)
-            # copying one output down waits for the whole program, as the
-            # fetch below would; the array keeps that host copy
-            np.asarray(pairs[0][0][1])
-        with tel.span("sim.fetch"):
-            finals = [
-                # state = (saved, done, comp_time, n_ckpt, work_lost, has_run, n_kills)
-                tuple(np.asarray(pairs[si][0][j]) for j in (1, 2, 3, 4, 6))
-                for si in range(S)
-            ]
-            recs_np = [tuple(np.asarray(x) for x in pairs[si][1]) for si in range(S)]  # (P, C)
-        tel.count("sweep.d2h_bytes", sum(a.nbytes for f in finals + recs_np for a in f))
-    elif impl == "interpret":
-        from repro.kernels.spot_sweep import kernel as K
-
-        consts = dict(
-            init_saved=float(scenario.initial_saved_work),
-            work_s=float(scenario.work_s),
-            t_c=float(params.t_c),
-            t_r=float(params.t_r),
-            hour_delta=delta,
-            interval=float(params.adapt_interval_s),
-            bin_s=float(adapt_tables.bin_s) if adapt_tables is not None else 0.0,
-            n_bins=int(adapt_tables.n_bins) if adapt_tables is not None else 1,
-        )
-        edges = ptr0 = tables = None
-        if need_edge:
-            flat, base, n, ptr0 = _edge_inputs(grid, params.t_r)
-            edges = (flat, base, n)
-        if need_adapt:
-            tables = (adapt_tables.flat, adapt_tables.off, adapt_tables.top)
-        out = K.sweep_pallas(
-            schemes, grid.A, grid.B, grid.valid, grid.horizon, consts,
-            ptr0=ptr0, edges=edges, tables=tables, block_c=block_c,
-        )
-        done, comp, ckpt, lost, kills, rex, rend, ruser = (np.asarray(x) for x in out)
-        finals = [(done[si], comp[si], ckpt[si], lost[si], kills[si]) for si in range(S)]
-        recs_np = [(rex[si].T, rend[si].T, ruser[si].T) for si in range(S)]
-    else:
-        raise ValueError(f"unknown spot_sweep impl {impl!r}")
-    return finals, recs_np

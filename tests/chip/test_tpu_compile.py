@@ -10,11 +10,9 @@ compile that passes says nothing about results or speed.
 * the fleet EET scoring kernel (``run_fleet(..., engine="jax")``) at a
   padded ``(256, 32)`` float64 wave.
 
-The Pallas sweep kernel has no test here: it does not compile natively
-(``repro.kernels.spot_sweep.kernel.NATIVE_UNSUPPORTED``).  The topology is
-described inside a fixture, never at import, so that every test worker
-collects the same tests and only the one that runs this file loads the TPU
-compiler.
+The topology is described inside a fixture, never at import, so that every
+test worker collects the same tests and only the one that runs this file
+loads the TPU compiler.
 """
 
 from __future__ import annotations

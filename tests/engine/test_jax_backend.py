@@ -37,6 +37,11 @@ def test_registry_resolves_jax_backend():
     assert run(Scenario.from_trace(synthetic_trace(IT, 3, seed=0), 3600.0, [0.36])).engine == "batch"
 
 
+def test_unknown_engine_name_lists_the_known_engines():
+    with pytest.raises(ValueError, match=r"'pallas'; expected auto\|batch\|reference\|jax$"):
+        get_engine("pallas")
+
+
 @pytest.mark.parametrize(
     "env_dir, backend", [(None, "tpu"), (None, "cpu"), ("custom", "tpu"), ("custom", "cpu")]
 )

@@ -68,17 +68,6 @@ def test_jax_sim_s_keeps_the_sweep_phases():
     assert res.timings.sim_s >= sum(c.dur for c in phases) > 0
 
 
-def test_pallas_timings_have_fused_sim_and_per_scheme_billing():
-    pytest.importorskip("jax")
-    from repro.engine import PallasEngine
-
-    res = PallasEngine(interpret=True).run(
-        _scenario(schemes=(Scheme.HOUR,))  # interpreter mode: keep it tiny
-    )
-    _assert_phase_times(res.timings, "pallas", (Scheme.HOUR,), sim_per_scheme=False)
-    assert res.timings.impl == "interpret"
-
-
 def test_reference_engine_reports_scalar_phase():
     res = get_engine("reference").run(_scenario(schemes=(Scheme.HOUR,)))
     assert isinstance(res.timings, PhaseTimings)  # every backend populates it
