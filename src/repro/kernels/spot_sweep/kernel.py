@@ -250,6 +250,19 @@ def _adapt_decoupled(A, B, valid, horizon, init_saved, work_s, t_c, t_r,
 # ---------------------------------------------------------------------------
 
 
+def record_code(rec):
+    """One ``uint8`` per (period, cell) in place of ``(rec_exists, rec_end,
+    rec_user)``: bit 0 set where a run ends, bit 1 where the user ended it.
+
+    ``rec_end`` is not carried: every scheme ends a run at ``comp_time[c]``
+    where the user ended it and at the period boundary ``B[c, p]`` elsewhere
+    (``period_step_masked`` and :func:`_adapt_decoupled` both build it so),
+    and the host holds both.
+    """
+    rec_exists, _, rec_user = rec
+    return rec_exists.astype(jnp.uint8) | (rec_user.astype(jnp.uint8) << 1)
+
+
 def build_sweep_scan(schemes: tuple[Scheme, ...], count_cb=None):
     """Build the fused multi-scheme sweep program.
 
@@ -265,9 +278,12 @@ def build_sweep_scan(schemes: tuple[Scheme, ...], count_cb=None):
     constants but the same grid shape reuses the compiled program.
     ``count_cb`` fires once per trace (the retrace-spy hook for tests).
 
-    Returns, per scheme (in order): ``(state, (rec_exists, rec_end,
-    rec_user))`` with the state 7-tuple of :func:`init_state` and records
-    shaped ``(P, C)``.
+    Returns, per scheme (in order): ``(state, code)`` with the state
+    7-tuple of :func:`init_state` and the run records as one ``(P, C)``
+    ``uint8`` array of :func:`record_code` (bit 0: a run ends here, bit 1:
+    the user ended it).  A run's end is left to the host, which rebuilds
+    it as ``comp_time[c]`` where the user ended the run and ``B[c, p]``
+    elsewhere.
     """
     schemes = tuple(schemes)
     scan_schemes = tuple(s for s in schemes if s != Scheme.ADAPT)
@@ -310,7 +326,7 @@ def build_sweep_scan(schemes: tuple[Scheme, ...], count_cb=None):
             for si, scheme in enumerate(scan_schemes):
                 st, rec = scheme_period_step(scheme, carry[si], a, b, valid, horizon, ptr0, c)
                 new_carry.append(st)
-                recs.append(rec)
+                recs.append(record_code(rec))
             return tuple(new_carry), tuple(recs)
 
         if scan_schemes:
@@ -320,13 +336,12 @@ def build_sweep_scan(schemes: tuple[Scheme, ...], count_cb=None):
         out, j = [], 0
         for scheme in schemes:
             if scheme == Scheme.ADAPT:
-                out.append(
-                    _adapt_decoupled(
-                        A_T.T, B_T.T, valid_T.T, horizon, init_saved, work_s,
-                        t_c, t_r, interval, tab_flat, tab_off, tab_top,
-                        bin_s, n_bins,
-                    )
+                state, rec = _adapt_decoupled(
+                    A_T.T, B_T.T, valid_T.T, horizon, init_saved, work_s,
+                    t_c, t_r, interval, tab_flat, tab_off, tab_top,
+                    bin_s, n_bins,
                 )
+                out.append((state, record_code(rec)))
             else:
                 out.append((carries[j], recs[j]))
                 j += 1

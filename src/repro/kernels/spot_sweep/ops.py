@@ -10,6 +10,8 @@ reference.
 The program simulates on-device (states *and* per-period run records — the
 billing inputs — accumulate in it) and shares the vectorized NumPy biller
 with the batch backend, so costs are bit-identical where the simulation is.
+The records come down as one ``uint8`` code per (period, cell); the host
+rebuilds each run's end from the grid and the fetched completion times.
 """
 
 from __future__ import annotations
@@ -132,7 +134,8 @@ def spot_sweep_grid(schemes, grid, scenario, adapt_tables=None):
     child spans of ``sim``: ``sim.inputs`` and ``sim.h2d`` (on a device-copy
     miss), ``sim.device`` (the program, from dispatch until its first output
     is on the host, so also the copies up still in flight) and ``sim.fetch``
-    (the other copies down; all of them counted in ``sweep.d2h_bytes``).
+    (the other copies down: the finals and one ``uint8`` record code per
+    (period, cell) a scheme, all of them counted in ``sweep.d2h_bytes``).
     """
     impl = "scan"
     schemes = tuple(schemes)
@@ -177,17 +180,20 @@ def spot_sweep_grid(schemes, grid, scenario, adapt_tables=None):
                 tuple(np.asarray(pairs[si][0][j]) for j in (1, 2, 3, 4, 6))
                 for si in range(S)
             ]
-            recs_np = [tuple(np.asarray(x) for x in pairs[si][1]) for si in range(S)]  # (P, C)
-        tel.count("sweep.d2h_bytes", sum(a.nbytes for f in finals + recs_np for a in f))
+            codes = [np.asarray(pairs[si][1]) for si in range(S)]  # (P, C) uint8
+        moved = [a for f in finals for a in f] + codes
+        tel.count("sweep.d2h_bytes", sum(a.nbytes for a in moved))
 
     for si, scheme in enumerate(schemes):
         with tel.span("bill", scheme=scheme.value):
             done, comp_time, n_ckpt, work_lost, n_kills = finals[si]
-            exists, end, user = recs_np[si]
-            pp, cc = np.nonzero(exists)
-            total, _ = _bill_runs_flat(
-                grid, pp, cc, grid.A[cc, pp], end[pp, cc], user[pp, cc], delta
-            )
+            # kernel.record_code: bit 0 a run ends, bit 1 the user ended it
+            # there, at comp_time; every other run ends at its period's end
+            code = codes[si]
+            pp, cc = np.nonzero(code & 1)
+            user = (code[pp, cc] & 2) != 0
+            end = np.where(user, comp_time[cc], grid.B[cc, pp])
+            total, _ = _bill_runs_flat(grid, pp, cc, grid.A[cc, pp], end, user, delta)
             outs[scheme] = {
                 "completed": done & np.isfinite(comp_time),
                 "completion_time": comp_time,

@@ -89,9 +89,11 @@ def test_byte_counters_are_the_arrays_moved():
     kwargs = ops.scan_scalars(sc, True, tables)
     kwargs.update({k: jnp.asarray(v) for k, v in host.items()})
     pairs = jax.block_until_ready(ops._scan_fn(schemes, jax)(**kwargs))
-    # per scheme: the five final fields the engine reads, and the run records
+    # per scheme: the five final fields the engine reads, and one uint8 run
+    # record code per (period, cell)
     finals = sum(state[j].nbytes for state, _ in pairs for j in (1, 2, 3, 4, 6))
-    records = sum(x.nbytes for _, recs in pairs for x in recs)
+    assert all(code.dtype == np.uint8 and code.shape == grid.B.T.shape for _, code in pairs)
+    records = len(schemes) * grid.B.size
     assert tel.counter("sweep.d2h_bytes") == finals + records > 0
 
 

@@ -112,3 +112,70 @@ def test_step_trace_edge_cases():
     )
     cand, _ = run_scan(sc)
     assert_outs_equal(run_ref(sc), cand)
+
+
+def _step_records(sc, grid, tables):
+    """Per scheme, ``(exists, end, user)`` as the program's building blocks
+    return them: ``period_step_masked`` (through ``scheme_period_step``) one
+    period at a time for NONE / OPT / HOUR / EDGE, ``_adapt_decoupled`` for
+    ADAPT."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.engine.jax_backend import _require_jax
+    from repro.kernels.spot_sweep import kernel as K
+
+    _require_jax()  # float64 on, as the program runs
+    host = sweep_ops.scan_arrays(grid, True, True, sc.params.t_r, tables)
+    d = {k: jnp.asarray(v) for k, v in host.items()}
+    s = sweep_ops.scan_scalars(sc, True, tables)
+    c = {k: s[k] for k in ("work_s", "t_c", "t_r", "hour_delta")}
+    c.update({k: d[k] for k in ("edges_flat", "edge_base", "edge_n")})
+    out = {}
+    for scheme in sc.schemes:
+        if scheme is Scheme.ADAPT:
+            _, recs = K._adapt_decoupled(
+                d["A_T"].T, d["B_T"].T, d["valid_T"].T, d["horizon"], s["init_saved"],
+                s["work_s"], s["t_c"], s["t_r"], s["interval"], d["tab_flat"], d["tab_off"],
+                d["tab_top"], s["bin_s"], s["n_bins"],
+            )
+            out[scheme] = tuple(np.asarray(x) for x in recs)
+            continue
+        step = jax.jit(lambda st, a, b, v, ptr0, scheme=scheme: K.scheme_period_step(
+            scheme, st, a, b, v, d["horizon"], ptr0, c))
+        state = K.init_state(grid.n_cells, s["init_saved"])
+        recs = []
+        for p in range(grid.A.shape[1]):
+            state, rec = step(state, d["A_T"][p], d["B_T"][p], d["valid_T"][p], d["ptr0_T"][p])
+            recs.append([np.asarray(x) for x in rec])
+        out[scheme] = tuple(np.stack(x) for x in zip(*recs))
+    return out
+
+
+def test_record_codes_rebuild_the_run_records():
+    """The program returns one record code per (period, cell); the host
+    rebuilds each run's end as ``comp_time`` where the user ended it and
+    ``B`` elsewhere.  That holds only while every scheme ends its runs
+    there, which this pins against the building blocks themselves."""
+    import jax
+
+    sc = small_scenario(days=20, seed=7, work_h=12.0, bids=[0.30, 0.345, 0.36, 0.40])
+    assert set(sc.schemes) == {Scheme.NONE, Scheme.OPT, Scheme.HOUR, Scheme.EDGE, Scheme.ADAPT}
+    cand, _ = run_scan(sc)
+    assert_outs_equal(run_ref(sc), cand)
+
+    grid, tables = _grid(sc)
+    kwargs = sweep_ops.scan_scalars(sc, True, tables)
+    kwargs.update(sweep_ops.scan_arrays(grid, True, True, sc.params.t_r, tables))
+    pairs = sweep_ops._scan_fn(sc.schemes, jax)(**kwargs)
+    steps = _step_records(sc, grid, tables)
+    for scheme, (state, code) in zip(sc.schemes, pairs):
+        code = np.asarray(code)
+        assert code.dtype == np.uint8 and code.shape == grid.B.T.shape
+        comp_time = np.asarray(state[2])
+        user = (code & 2) != 0
+        rebuilt = ((code & 1) != 0, np.where(user, comp_time[None, :], grid.B.T), user)
+        exists, end, rec_user = steps[scheme]
+        assert rec_user.any() and (exists & ~rec_user).any(), scheme  # both ends occur
+        for name, got, want in zip(("exists", "end", "user"), rebuilt, (exists, end, rec_user)):
+            np.testing.assert_array_equal(got, want, err_msg=f"{scheme.value}.{name}")
